@@ -16,7 +16,6 @@ os.environ["XLA_FLAGS"] = (
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
-from repro import compat  # noqa: E402
 from repro.core.params import IndexParams, SearchParams  # noqa: E402
 from repro.distributed.ann import DistParams, ShardedSession  # noqa: E402
 
@@ -27,7 +26,7 @@ dp = DistParams(index=IndexParams(
 ))
 rng = np.random.default_rng(0)
 
-with compat.use_mesh(mesh):
+with jax.set_mesh(mesh):
     # the sharded session owns the stacked per-shard state (donated through
     # every update step) and dispatches ops async — flush() to synchronize
     sess = ShardedSession(dp, mesh, strategy="global", seed=0)

@@ -200,16 +200,12 @@ def _splice_apply(
     cap, d_out = state.capacity, state.d_out
 
     # group the planned additions per surviving row u (each u holds ≤ d_out
-    # lanes — one per deleted out-neighbor)
-    adds, touched_u = group_by_destination(
+    # lanes — one per deleted out-neighbor), in a compact frame over the
+    # ≤ B·d_in rows that actually gain an edge
+    uid, adds_rows, u_ok = group_by_destination(
         z_flat, u_flat, u_valid & (z_flat != NULL), cap, d_out
     )
-    # compact frame over the ≤ B·d_in rows that actually gain an edge
-    R_u = min(u_flat.shape[0], cap)
-    _, uid = jax.lax.top_k(touched_u.astype(jnp.int32), R_u)
-    u_ok = touched_u[uid]
     uv = jnp.where(u_ok, uid, 0).astype(jnp.int32)
-    adds_rows = adds[uv]                                  # [R_u, d_out]
     # dedup additions within a row (several x's may pick the same z for u)
     eqa = (adds_rows[:, :, None] == adds_rows[:, None, :]) \
         & (adds_rows != NULL)[:, :, None]

@@ -16,6 +16,9 @@ import jax
 import jax.numpy as jnp
 
 NEG_INF = jnp.float32(-jnp.inf)
+# fp32 scores mean fp32 products: a TPU's default matmul precision would
+# round the operands to bf16 (CPU backends ignore the flag)
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def sqnorm(x: jax.Array) -> jax.Array:
@@ -45,7 +48,8 @@ def scores_vs_rows(
     metric: str,
 ) -> jax.Array:
     """Scores of one query against n gathered rows (beam-expansion path)."""
-    dot = rows.astype(jnp.float32) @ q.astype(jnp.float32)
+    dot = jnp.matmul(rows.astype(jnp.float32), q.astype(jnp.float32),
+                     precision=HIGHEST)
     if metric == "l2":
         return 2.0 * dot - row_sqnorms
     return dot
@@ -59,7 +63,8 @@ def score_matrix(
 ) -> jax.Array:
     """[b, m] score matrix — the MXU-form bulk path (ground truth, rebuild,
     DLRM retrieval_cand)."""
-    dots = q.astype(jnp.float32) @ x.astype(jnp.float32).T
+    dots = jnp.matmul(q.astype(jnp.float32), x.astype(jnp.float32).T,
+                      precision=HIGHEST)
     if metric == "l2":
         return 2.0 * dots - x_sqnorms[None, :]
     return dots

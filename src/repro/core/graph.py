@@ -327,6 +327,16 @@ def rebuild_radj_rows(state: GraphState, touched: jax.Array) -> GraphState:
     return dataclasses.replace(state, adj=adj, radj=radj)
 
 
+def _segment_rank(sorted_key: jax.Array) -> jax.Array:
+    """Position of each lane of a sorted key array within its run of equal
+    keys (0 for the first lane of every run)."""
+    n = sorted_key.shape[0]
+    lane = jnp.arange(n, dtype=jnp.int32)
+    new_run = jnp.concatenate(
+        [jnp.ones((1,), bool), sorted_key[1:] != sorted_key[:-1]])
+    return lane - jax.lax.cummax(jnp.where(new_run, lane, 0))
+
+
 def apply_row_updates(
     state: GraphState,
     us: jax.Array,        # i32[R]        rows to replace (unique where valid)
@@ -336,23 +346,26 @@ def apply_row_updates(
     """Incremental scatter-based edge application (the hot-path applier).
 
     Writes the forward rows with one OOB-dropping scatter and *patches*
-    ``radj`` instead of recomputing it: removals are found by testing every
-    reverse entry against its (possibly rewritten) source row — pure
-    gathers — and additions are grouped by destination with one small sort
-    over the R·d_out addition lanes, then slotted into the NULL holes of
-    their reverse rows via a cumsum ranking. No sort over the full edge
-    table (XLA's O(cap·d_out) sort/scatter is what made the naive rebuild
-    CPU-bound).
+    ``radj`` instead of recomputing it. All work and temporaries scale with
+    the R·d_out edge lanes of the batch, never with capacity:
 
-    Bounded in-degree: existing in-edges keep priority; additions are
-    admitted into the remaining holes in deterministic group order and
-    **refused** beyond that (the forward entry is dropped too, so I1 holds
-    exactly — same semantics family as scalar ``add_edge`` refusal, minus
-    the sequential arrival order).
+      · removals — for each dropped edge u→v (in u's old row, not its new
+        one), I1 puts u in ``radj[v]``: the row is gathered, u found, and
+        NULL scattered at that entry;
+      · additions — edges in a new row but not its old one are ranked per
+        destination by one stable sort over the R·d_out lanes (rank order
+        = flat (row, slot) order), and the rank-h addition fills the h-th
+        NULL hole (in entry order) of its destination's post-removal row.
+
+    Bounded in-degree: existing in-edges keep priority; additions ranked
+    past the holes are **refused** (the forward entry is dropped too, so
+    I1 holds exactly — same semantics family as scalar ``add_edge``
+    refusal, minus the sequential arrival order).
 
     ``new_rows`` must already be sanitized (no self edges / dups /
     non-present targets) — use ``set_out_edges_batch`` for the checked
-    wrapper. Valid ``us`` must be unique.
+    wrapper. Valid ``us`` must be unique, and the incoming state must
+    satisfy I1 (every public op leaves it so).
     """
     cap, d_out, d_in = state.capacity, state.d_out, state.d_in
     R = us.shape[0]
@@ -361,70 +374,43 @@ def apply_row_updates(
     wsu = jnp.where(valid, us, cap)  # OOB parks invalid lanes (mode="drop")
     old_rows = jnp.where(valid[:, None], state.adj[su], NULL)
     new_rows = jnp.where(valid[:, None], new_rows, NULL)
+    src = jnp.broadcast_to(su[:, None], (R, d_out)).reshape(-1)  # [E]
+    entry = jnp.broadcast_to(
+        jnp.arange(d_in, dtype=jnp.int32), (R * d_out, d_in))
 
-    # ---- removals: reverse entry (v, i) = u dies iff u's row was rewritten
-    # and v is no longer in it (I1 guarantees the entry matched adj before)
-    row_of = jnp.full((cap + 1,), -1, jnp.int32).at[wsu].set(
-        jnp.arange(R, dtype=jnp.int32), mode="drop"
-    )[:cap]
-    rv = state.radj
-    r_idx = jnp.where(rv != NULL, row_of[jnp.maximum(rv, 0)], -1)
-    nr = new_rows[jnp.maximum(r_idx, 0)]          # [cap, d_in, d_out]
-    still = jnp.any(nr == jnp.arange(cap)[:, None, None], axis=2)
-    radj1 = jnp.where((r_idx >= 0) & ~still, NULL, rv)
+    # ---- removals: NULL u's entry in radj[v] for every dropped edge u→v
+    gone = (old_rows != NULL) & ~jnp.any(
+        old_rows[:, :, None] == new_rows[:, None, :], axis=2)
+    gone_v = jnp.where(gone, old_rows, cap).reshape(-1)
+    hit = state.radj[jnp.minimum(gone_v, cap - 1)] == src[:, None]
+    radj = state.radj.at[
+        jnp.where(hit & (gone_v < cap)[:, None], gone_v[:, None], cap), entry
+    ].set(NULL, mode="drop")
 
-    # ---- additions: edges in new_rows but not old_rows, grouped by dest —
-    # one sort over R·d_out lanes only
-    add_m = (new_rows != NULL) & ~jnp.any(
-        new_rows[:, :, None] == old_rows[:, None, :], axis=2
-    )
-    src = jnp.broadcast_to(su[:, None], (R, d_out)).reshape(-1)
+    # ---- additions: rank each added edge among the additions to its
+    # destination — one stable sort over the R·d_out lanes
+    add = (new_rows != NULL) & ~jnp.any(
+        new_rows[:, :, None] == old_rows[:, None, :], axis=2)
+    add = add.reshape(-1)
     dst = new_rows.reshape(-1)
-    add_flat = add_m.reshape(-1)
-    E = dst.shape[0]
-    key_dst = jnp.where(add_flat, dst, cap)
-    order = jnp.argsort(key_dst, stable=True)
-    sorted_key = key_dst[order]
-    sorted_src = src[order]
-    vids = jnp.arange(cap, dtype=key_dst.dtype)
-    start = jnp.searchsorted(sorted_key, vids, side="left")
-    end = jnp.searchsorted(sorted_key, vids, side="right")
-    idx = start[:, None] + jnp.arange(d_in)[None, :]
-    add_rows = jnp.where(
-        idx < end[:, None], sorted_src[jnp.clip(idx, 0, E - 1)], NULL
-    )                                              # [cap, d_in] rank order
-
-    # admit additions into the holes left after removals; refuse the rest.
-    # A lane's group rank is its position in add_rows[v] (sources are unique
-    # per destination), so refusal is a compare — no inverse-permutation sort
-    holes = d_in - jnp.sum(radj1 != NULL, axis=1)  # [cap]
-    ar = add_rows[jnp.clip(new_rows, 0, cap - 1)]  # [R, d_out, d_in]
-    match = ar == su[:, None, None]
-    past_holes = (
-        jnp.arange(d_in)[None, None, :]
-        >= holes[jnp.clip(new_rows, 0, cap - 1)][:, :, None]
-    )
-    # refused: admitted past the holes, or ranked ≥ d_in (never grouped)
-    refused = add_m & (
-        jnp.any(match & past_holes, axis=2) | ~jnp.any(match, axis=2)
-    )
-    final_rows = jnp.where(refused, NULL, new_rows)
+    key = jnp.where(add, dst, cap)
+    order = jnp.argsort(key, stable=True)
+    rank = jnp.zeros_like(order).at[order].set(_segment_rank(key[order]))
+    # admit additions into the holes left after removals; refuse the rest
+    isnull = radj[jnp.where(add, dst, 0)] == NULL      # [E, d_in]
+    admit = add & (rank < jnp.sum(isnull, axis=1))
+    final_rows = jnp.where((add & ~admit).reshape(R, d_out), NULL, new_rows)
     adj = state.adj.at[wsu].set(final_rows, mode="drop")
-
-    # fill the holes, in group-rank order (hole h takes addition h; holes
-    # are counted by a per-row cumsum, so no per-row sort is needed)
-    isnull = radj1 == NULL
+    # the rank-h addition takes the h-th hole of its destination row
     hole_rank = jnp.cumsum(isnull.astype(jnp.int32), axis=1) - 1
-    fill = jnp.take_along_axis(
-        add_rows, jnp.clip(hole_rank, 0, d_in - 1), axis=1
-    )
-    radj2 = jnp.where(isnull, fill, radj1)
+    pos = jnp.argmax(isnull & (hole_rank == rank[:, None]), axis=1)
+    radj = radj.at[jnp.where(admit, dst, cap), pos].set(src, mode="drop")
     # staleness stamps (I7): every rewritten out-row takes the current tclock
     # (OP_REFINE picks the lowest-touch alive slots); one bump per call keeps
     # within-batch ties broken by slot id, deterministically
     touch = state.touch.at[wsu].set(state.tclock, mode="drop")
     return dataclasses.replace(
-        state, adj=adj, radj=radj2, touch=touch, tclock=state.tclock + 1
+        state, adj=adj, radj=radj, touch=touch, tclock=state.tclock + 1
     )
 
 
@@ -471,27 +457,33 @@ def group_by_destination(
     valid: jax.Array,     # bool[E]
     capacity: int,
     max_per_row: int,
-) -> tuple[jax.Array, jax.Array]:
-    """Scatter an edge list into per-destination rows.
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Group an edge list by destination into a compact frame.
 
-    Returns (rows i32[capacity, max_per_row] NULL padded, touched
-    bool[capacity]). Edges ranked ≥ ``max_per_row`` within their destination
-    are dropped (rank order = input order, deterministic). The grouping
-    engine behind back-link application and LOCAL splice batching.
-    Scatter-free (segment gather from the sorted edge list).
+    Returns (dests i32[F], rows i32[F, max_per_row], ok bool[F]) with
+    F = min(E, capacity): frame slot t holds the t-th smallest destination
+    that receives a valid edge and its sources in input order, NULL padded.
+    Edges ranked ≥ ``max_per_row`` within their destination are dropped;
+    slots past the number of distinct destinations are not ``ok`` (dest
+    NULL). The grouping engine behind back-link application and LOCAL
+    splice batching — one sort over the E lanes, nothing sized by capacity.
     """
     E = dst.shape[0]
-    key_dst = jnp.where(valid, dst, capacity)
-    order = jnp.argsort(key_dst, stable=True)
-    sorted_key = key_dst[order]
-    sorted_src = jnp.where(valid, src, NULL)[order]
-    vids = jnp.arange(capacity, dtype=key_dst.dtype)
-    start = jnp.searchsorted(sorted_key, vids, side="left")
-    end = jnp.searchsorted(sorted_key, vids, side="right")
-    idx = start[:, None] + jnp.arange(max_per_row)[None, :]
-    take = idx < end[:, None]
-    rows = jnp.where(take, sorted_src[jnp.clip(idx, 0, E - 1)], NULL)
-    return rows.astype(jnp.int32), end > start
+    F = min(E, capacity)
+    key = jnp.where(valid, dst, capacity)
+    order = jnp.argsort(key, stable=True)
+    sorted_key = key[order]
+    sorted_src = src[order]
+    real = sorted_key < capacity
+    rank = _segment_rank(sorted_key)
+    seg = jnp.cumsum((real & (rank == 0)).astype(jnp.int32)) - 1
+    dests = jnp.full((F,), NULL, jnp.int32).at[
+        jnp.where(real & (rank == 0), seg, F)].set(sorted_key, mode="drop")
+    rows = jnp.full((F, max_per_row), NULL, jnp.int32).at[
+        jnp.where(real & (rank < max_per_row), seg, F),
+        jnp.minimum(rank, max_per_row - 1),
+    ].set(sorted_src, mode="drop")
+    return dests, rows, dests != NULL
 
 
 # ---------------------------------------------------------------------------

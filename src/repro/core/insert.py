@@ -236,24 +236,18 @@ def insert_batch_impl(
         # row+1 candidates per arrival (deviation bounded, B=1 unaffected).
         src = jnp.broadcast_to(slots[:, None], nbrs.shape).reshape(-1)
         dst = nbrs.reshape(-1)
-        bl, touched_z = group_by_destination(src, dst, dst != NULL, cap, d_out)
-
         # compact frame: all work below happens on the ≤ B·d_out rows that
-        # actually receive back-links (top_k indices are distinct)
-        R_z = min(B * d_out, cap)
-        _, zid = jax.lax.top_k(touched_z.astype(jnp.int32), R_z)
-        z_ok = touched_z[zid]
+        # actually receive back-links, in ascending row id order
+        zid, bl_rows, z_ok = group_by_destination(
+            src, dst, dst != NULL, cap, d_out)
         zv = jnp.where(z_ok, zid, 0).astype(jnp.int32)
         # virtual current row: a z that is itself a freshly inserted slot
         # sees its just-selected forward row (mutual intra-batch selection)
-        row_of_slot = jnp.full((cap + 1,), -1, jnp.int32).at[wslots].set(
-            jnp.arange(B, dtype=jnp.int32), mode="drop"
-        )[:cap]
-        sidx = row_of_slot[zv]
+        is_slot = (zv[:, None] == slots[None, :]) & ok[None, :]
+        sidx = jnp.argmax(is_slot, axis=1)
         old_z = jnp.where(
-            (sidx >= 0)[:, None], nbrs[jnp.maximum(sidx, 0)], state.adj[zv]
+            jnp.any(is_slot, axis=1)[:, None], nbrs[sidx], state.adj[zv]
         )                                                    # [R_z, d_out]
-        bl_rows = bl[zv]                                     # [R_z, d_out]
         # mutual selection: the virtual row may already hold the back-link
         dup = jnp.any(
             bl_rows[:, :, None] == old_z[:, None, :], axis=2
@@ -273,8 +267,9 @@ def insert_batch_impl(
 
         # combined application; where z is itself a slot, the z row is the
         # complete (forward ∪ back-link) row and supersedes the slot lane
-        slot_valid = ok & ~touched_z[jnp.where(ok, slots, 0)]
-        us_all = jnp.concatenate([slots, zid.astype(jnp.int32)])
+        slot_valid = ok & ~jnp.any(
+            slots[:, None] == jnp.where(z_ok, zid, NULL)[None, :], axis=1)
+        us_all = jnp.concatenate([slots, zid])
         rows_all = jnp.concatenate([nbrs, z_rows], axis=0)
         valid_all = jnp.concatenate([slot_valid, z_ok])
         state = set_out_edges_batch(state, us_all, rows_all, valid_all)

@@ -87,7 +87,8 @@ def scores_vs_codes(
     computed from the codes — no fp32 row or sqnorm cache is touched.
     """
     c = codes.astype(jnp.float32)
-    dots = jnp.einsum("...d,d->...", c, q.astype(jnp.float32))
+    dots = jnp.einsum("...d,d->...", c, q.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST)
     if metric == "l2":
         return scales * (2.0 * dots - scales * jnp.sum(c * c, axis=-1))
     return scales * dots

@@ -41,7 +41,7 @@ def select_neighbors(
     n = cand_ids.shape[0]
     x32 = x_vec.astype(jnp.float32)
     v32 = cand_vecs.astype(jnp.float32)
-    dots = v32 @ x32  # [n]
+    dots = jnp.matmul(v32, x32, precision=distances.HIGHEST)  # [n]
 
     if metric == "l2":
         order_key = 2.0 * dots - distances.sqnorm(v32)   # x as query

@@ -10,10 +10,11 @@ shard. The 'pod' axis holds index replicas and shards the query stream
   query : queries replicated within a pod → every shard beam-searches its
           subgraph → all_gather(k per shard) → top-k merge. Collective bytes
           per query = P·k·8 — independent of index size.
-  insert: routed by hash → SPMD masked insert (only the owner's mask is
-          hot) through the vectorized insert pipeline (DESIGN.md §4): every
-          shard runs ONE batched search + scatter edge application for its
-          routed slice, inline inside shard_map (no nested jit).
+  insert: routed by hash → rows grouped per owning shard on the host
+          (``shard_blocks``) → every shard runs the vectorized insert
+          pipeline (DESIGN.md §4) on its own block only: ONE batched search
+          + scatter edge application, inline inside shard_map (no nested
+          jit).
   delete: global id = shard·cap_local + local id → owner-masked
           delete_batch with the configured strategy (GLOBAL repair searches
           are shard-local by construction).
@@ -32,9 +33,8 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
-
-from repro import compat
 
 from repro.core import consolidate as consolidate_mod
 from repro.core import delete as delete_mod
@@ -83,18 +83,24 @@ class DistParams:
 
 
 def init_sharded_state(dp: DistParams, mesh) -> GraphState:
-    """Host-side init of the stacked per-shard states [P, cap_local, ...]."""
+    """Init of the stacked per-shard states [P, cap_local, ...], built in
+    place: each device materializes only its own shard."""
     n_shards = 1
     for a in dp.shard_axes:
         n_shards *= mesh.shape[a]
-    one = init_graph(
-        dp.index.capacity, dp.index.dim, d_out=dp.index.d_out,
-        d_in=dp.index.eff_d_in, metric=dp.index.metric,
-        dtype=jnp.dtype(dp.vec_dtype),
-    )
-    return jax.tree.map(
-        lambda x: jnp.broadcast_to(x[None], (n_shards,) + x.shape), one
-    )
+    shardings = jax.tree.map(
+        lambda _: NamedSharding(mesh, P(dp.shard_axes)), init_specs_tree(dp))
+
+    def build() -> GraphState:
+        one = init_graph(
+            dp.index.capacity, dp.index.dim, d_out=dp.index.d_out,
+            d_in=dp.index.eff_d_in, metric=dp.index.metric,
+            dtype=jnp.dtype(dp.vec_dtype),
+        )
+        return jax.tree.map(
+            lambda x: jnp.broadcast_to(x[None], (n_shards,) + x.shape), one)
+
+    return jax.jit(build, out_shardings=shardings)()
 
 
 def _local(state_stacked: GraphState) -> GraphState:
@@ -109,7 +115,7 @@ def _restack(state: GraphState) -> GraphState:
 def _shard_index(axes) -> jax.Array:
     idx = jnp.int32(0)
     for a in axes:
-        idx = idx * compat.axis_size(a) + jax.lax.axis_index(a)
+        idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     return idx
 
 
@@ -171,7 +177,7 @@ def make_query_step(dp: DistParams, mesh):
             top_s, top_i = _merge(res.scores, gids, axes, k)
         return top_i, top_s
 
-    smapped = compat.shard_map(
+    smapped = jax.shard_map(
         _step, mesh=mesh,
         in_specs=(state_spec, q_spec, P()),
         out_specs=(q_spec, q_spec),
@@ -180,33 +186,61 @@ def make_query_step(dp: DistParams, mesh):
     return jax.jit(smapped)
 
 
+def shard_blocks(vecs, route, n_shards: int):
+    """Group the rows of a routed insert by owning shard (``route % n``).
+
+    Returns (blocks f32[n, m, dim], rows i32[n, m], valid bool[n, m],
+    pos i64[B]): row i lands at flat position ``pos[i]`` of the ``[n·m]``
+    frame, in arrival order within its shard, and ``rows`` holds each
+    lane's index in the batch. ``m`` is the largest share rounded up to a
+    power of two, so skewed routes recompile the insert step a bounded
+    number of times.
+    """
+    vecs = np.asarray(vecs, np.float32)
+    owner = np.asarray(route, np.int64) % n_shards
+    n = owner.shape[0]
+    counts = np.bincount(owner, minlength=n_shards)
+    m = 1 << max(int(counts.max(initial=0)) - 1, 0).bit_length()
+    order = np.argsort(owner, kind="stable")
+    rank = np.empty_like(owner)
+    rank[order] = np.arange(n) - (np.cumsum(counts) - counts)[owner[order]]
+    pos = owner * m + rank
+    blocks = np.zeros((n_shards * m, vecs.shape[1]), np.float32)
+    blocks[pos] = vecs
+    rows = np.zeros((n_shards * m,), np.int32)
+    rows[pos] = np.arange(n)
+    valid = np.zeros((n_shards * m,), bool)
+    valid[pos] = True
+    return (blocks.reshape(n_shards, m, -1), rows.reshape(n_shards, m),
+            valid.reshape(n_shards, m), pos)
+
+
 def make_insert_step(dp: DistParams, mesh):
-    """Routed batch insert: vectors f32[B, dim] + router ids i32[B]."""
+    """Routed batch insert over per-shard row blocks (``shard_blocks``):
+    (blocks, rows, valid) → gids i32[P, m] on every device. Each shard runs
+    the insert pipeline on its own rows only."""
     axes = dp.axes
     state_spec = jax.tree.map(lambda _: P(axes), init_specs_tree(dp))
     stride = dp.gid_stride()
 
-    def _step(state_stacked, vecs, route, key):
+    def _step(state_stacked, blocks, rows, valid, key):
         state = _local(state_stacked)
         shard = _shard_index(axes)
-        n_shards = 1
-        for a in axes:
-            n_shards *= compat.axis_size(a)
-        mine = (route % n_shards) == shard
         key = jax.random.fold_in(key, shard)
+        # lane j folds key_offset + j: offset it so every row folds its own
+        # index in the routed batch, whatever its place in the shard block
+        offset = rows[0] - jnp.arange(rows.shape[1], dtype=jnp.int32)
         # traceable impl, not the jitted wrapper: runs inline in shard_map
         state, ids = insert_mod.insert_batch_impl(
-            state, vecs, mine, key, dp.index
+            state, blocks[0], valid[0], key, dp.index, key_offset=offset
         )
         gids = jnp.where(ids != NULL, ids + shard * stride, NULL)
-        # owner announces its assigned gid; everyone else holds NULL(-1);
-        # pmax is exact since real gids are >= 0
-        gids = jax.lax.pmax(jnp.where(mine, gids, NULL), axes)
-        return _restack(state), gids
+        # every device holds every shard's gids: [P, m], shard-major
+        return _restack(state), jax.lax.all_gather(gids, axes)
 
-    smapped = compat.shard_map(
+    smapped = jax.shard_map(
         _step, mesh=mesh,
-        in_specs=(state_spec, P(), P(), P()),
+        in_specs=(state_spec, P(axes), P(axes), P(axes), P()),
         out_specs=(state_spec, P()),
         check_vma=False,
     )
@@ -235,7 +269,7 @@ def make_delete_step(dp: DistParams, mesh, strategy: str):
         )
         return _restack(state)
 
-    smapped = compat.shard_map(
+    smapped = jax.shard_map(
         _step, mesh=mesh,
         in_specs=(state_spec, P(), P()),
         out_specs=state_spec,
@@ -269,7 +303,7 @@ def make_consolidate_step(dp: DistParams, mesh):
         )
         return _restack(state)
 
-    smapped = compat.shard_map(
+    smapped = jax.shard_map(
         _step, mesh=mesh,
         in_specs=(state_spec, P()),
         out_specs=state_spec,
@@ -303,7 +337,10 @@ def distributed_query(state, queries, key, dp, mesh):
 
 
 def distributed_insert(state, vecs, route, key, dp, mesh):
-    return make_insert_step(dp, mesh)(state, vecs, route, key)
+    n_shards = int(np.prod([mesh.shape[a] for a in dp.shard_axes]))
+    blocks, rows, valid, pos = shard_blocks(vecs, route, n_shards)
+    state, gids = make_insert_step(dp, mesh)(state, blocks, rows, valid, key)
+    return state, gids.reshape(-1)[pos]
 
 
 def distributed_delete(state, gids, key, dp, mesh, strategy="global"):
@@ -331,6 +368,7 @@ class ShardedSession:
 
         self.dp = dp
         self.mesh = mesh
+        self._n_shards = int(np.prod([mesh.shape[a] for a in dp.shard_axes]))
         self._strategy = (strategy if strategy is not None
                           else dp.index.maintenance.strategy)
         self._build_steps()
@@ -406,10 +444,10 @@ class ShardedSession:
             self._ensure_room(n)  # consolidate_s / grow_s phases
         faults.crash_point("sharded-pre-dispatch")
         t0 = time.perf_counter()
+        blocks, rows, valid, pos = shard_blocks(vecs, route, self._n_shards)
         self.state, gids = self._insert_step(
-            self.state, jnp.asarray(vecs),
-            jnp.asarray(route, jnp.int32), self._op_key(),
-        )
+            self.state, blocks, rows, valid, self._op_key())
+        gids = gids.reshape(-1)[pos]
         self._free_floor = max(self._free_floor - n, 0)
         self._pending.append(gids)
         self._insert_results.append(gids)

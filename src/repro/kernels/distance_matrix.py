@@ -43,17 +43,16 @@ def _score_kernel(x_ref, xsq_ref, q_ref, o_ref, *, n_d_tiles: int, metric: str):
 
     q = q_ref[...].astype(jnp.float32)
     x = x_ref[...].astype(jnp.float32)
-    acc = 2.0 * jax.lax.dot_general(
-        q, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) if metric == "l2" else jax.lax.dot_general(
-        q, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    dots = jax.lax.dot_general(
+        q, x, (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     )
-    o_ref[...] += acc
+    o_ref[...] += 2.0 * dots if metric == "l2" else dots
 
     @pl.when(kd == n_d_tiles - 1)
     def _finish():
         if metric == "l2":
-            o_ref[...] -= xsq_ref[...][None, :].astype(jnp.float32)
+            o_ref[...] -= xsq_ref[...].astype(jnp.float32)
 
 
 def score_matrix_pallas(
@@ -65,7 +64,7 @@ def score_matrix_pallas(
     block_b: int = 128,
     block_m: int = 256,
     block_d: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """[B, M] scores. Caller pads B/M/d to block multiples (see ops.py)."""
     B, d = q.shape
@@ -77,13 +76,13 @@ def score_matrix_pallas(
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_m, block_d), lambda b, m, kd: (m, kd)),
-            pl.BlockSpec((block_m,), lambda b, m, kd: (m,)),
+            pl.BlockSpec((1, block_m), lambda b, m, kd: (0, m)),
             pl.BlockSpec((block_b, block_d), lambda b, m, kd: (b, kd)),
         ],
         out_specs=pl.BlockSpec((block_b, block_m), lambda b, m, kd: (b, m)),
         out_shape=jax.ShapeDtypeStruct((B, M), jnp.float32),
         interpret=interpret,
-    )(x, xsq, q)
+    )(x, xsq.reshape(1, M), q)
 
 
 # ---------------------------------------------------------------------------
@@ -123,9 +122,10 @@ def _topk_kernel(
     q = q_ref[...].astype(jnp.float32)
     x = x_ref[...].astype(jnp.float32)
     dots = jax.lax.dot_general(
-        q, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        q, x, (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     )
-    scores = 2.0 * dots - xsq_ref[...][None, :] if metric == "l2" else dots
+    scores = 2.0 * dots - xsq_ref[...] if metric == "l2" else dots
     local_ids = (
         jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1) + m_idx * block_m
     )
@@ -153,7 +153,7 @@ def score_topk_pallas(
     block_b: int = 64,
     block_m: int = 256,
     n_valid: int | None = None,
-    interpret: bool = True,
+    interpret: bool,
 ) -> tuple[jax.Array, jax.Array]:
     """Fused (scores f32[B,k], ids i32[B,k]) without the [B,M] HBM matrix."""
     B, d = q.shape
@@ -168,7 +168,7 @@ def score_topk_pallas(
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_m, d), lambda b, m: (m, 0)),
-            pl.BlockSpec((block_m,), lambda b, m: (m,)),
+            pl.BlockSpec((1, block_m), lambda b, m: (0, m)),
             pl.BlockSpec((block_b, d), lambda b, m: (b, 0)),
         ],
         out_specs=[
@@ -184,4 +184,4 @@ def score_topk_pallas(
             pltpu.VMEM((block_b, k), jnp.int32),
         ],
         interpret=interpret,
-    )(x, xsq, q)
+    )(x, xsq.reshape(1, M), q)

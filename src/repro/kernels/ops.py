@@ -1,8 +1,8 @@
 """jit'd public wrappers around the Pallas kernels.
 
 Handles padding to block multiples, invalid-id fixup, dtype policy (bf16/f32
-inputs, fp32 accumulation), and the interpret-mode switch (interpret=True on
-CPU — the container target; False when an actual TPU backend is present).
+inputs, fp32 accumulation), and the interpret-mode switch: interpret mode on
+the CPU backend only, compiled kernels everywhere else.
 
 Capacity-tier contract (DESIGN.md §9): the growth engine produces table
 sizes that are NOT powers of two (geometric tiers, ``max_capacity`` clips),
@@ -16,8 +16,8 @@ audited per kernel and pinned by the {2^k, 2^k+1, 3·2^k} sweep in
     (authoritative for every metric) AND ``xsq`` is padded with +inf (l2
     belt-and-braces), so a padded tail row can never win a top-k slot.
   · ``gather_scores`` — ids are validated against the true M here and
-    clamped before the kernel; the row BlockSpec indexes exact rows, so no
-    tail row is ever DMA'd, and invalid lanes resolve to -inf outside.
+    clamped before the kernel; the row DMAs address exact rows, so no
+    tail row is ever read, and invalid lanes resolve to -inf outside.
   · ``gather_scores_q8`` — identical id-validation/clamp/-inf contract as
     ``gather_scores``, over int8 codes + per-row scales (DESIGN.md §10);
     the dim pad value 0 is inert in both the dot and the Σcodes² term.
@@ -36,14 +36,14 @@ NEG_INF = float("-inf")
 
 
 def on_tpu() -> bool:
-    """True when the default backend is a real TPU (not interpret mode)."""
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    """True when the default backend is a real TPU."""
+    return jax.devices()[0].platform == "tpu"
 
 
-_on_tpu = on_tpu  # internal alias kept for the jit'd wrappers below
+def _interpret(flag: bool | None) -> bool:
+    """Interpret mode is the CPU backend's emulator and nothing else: on any
+    other backend the kernel is compiled (and raises where it cannot be)."""
+    return jax.default_backend() == "cpu" if flag is None else flag
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int, value=0.0) -> jax.Array:
@@ -71,7 +71,7 @@ def score_matrix(
     interpret: bool | None = None,
 ) -> jax.Array:
     """[B, M] fp32 scores via the tiled Pallas kernel (padded + cropped)."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = _interpret(interpret)
     B, M = q.shape[0], x.shape[0]
     block_b = min(block_b, max(8, B))
     block_m = min(block_m, max(8, M))
@@ -100,7 +100,7 @@ def score_topk(
     interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Fused brute-force top-k: (scores f32[B,k], ids i32[B,k])."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = _interpret(interpret)
     B, M = q.shape[0], x.shape[0]
     block_b = min(block_b, max(8, B))
     block_m = min(block_m, max(k, 8, M))
@@ -133,14 +133,15 @@ def gather_scores(
     interpret: bool | None = None,
 ) -> jax.Array:
     """[B, C] fused gather+distance; invalid ids (< 0 or >= N) → -inf."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = _interpret(interpret)
     N = table.shape[0]
     valid = (ids >= 0) & (ids < N)
     safe = jnp.where(valid, ids, 0).astype(jnp.int32)
+    # rows are DMA'd whole: the lane dim is padded to the 128-lane tile
     tp = _pad_to(table, 1, 128)
     qp = _pad_to(q, 1, 128)
     s = _gd.gather_scores_pallas(
-        tp, tsq.astype(jnp.float32), safe, qp, metric=metric,
+        tp, tsq.astype(jnp.float32)[safe], safe, qp, metric=metric,
         interpret=interpret,
     )
     return jnp.where(valid, s, NEG_INF)
@@ -159,14 +160,14 @@ def gather_scores_q8(
     """[B, C] fused gather+asymmetric-distance over int8 codes; invalid ids
     (< 0 or >= N) → -inf. Same contract as ``gather_scores`` with the fp32
     row read replaced by a d-byte code row dequantized in-register."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = _interpret(interpret)
     N = codes.shape[0]
     valid = (ids >= 0) & (ids < N)
     safe = jnp.where(valid, ids, 0).astype(jnp.int32)
     cp = _pad_to(codes, 1, 128, value=0)
     qp = _pad_to(q, 1, 128)
     s = _gd.gather_scores_q8_pallas(
-        cp, scales.astype(jnp.float32), safe, qp, metric=metric,
+        cp, scales.astype(jnp.float32)[safe], safe, qp, metric=metric,
         interpret=interpret,
     )
     return jnp.where(valid, s, NEG_INF)

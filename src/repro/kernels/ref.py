@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 
 NEG_INF = float("-inf")
+HIGHEST = jax.lax.Precision.HIGHEST  # exact fp32 products on every backend
 
 
 def ref_score_matrix(
@@ -18,7 +19,8 @@ def ref_score_matrix(
     metric: str = "l2",
 ) -> jax.Array:
     """[B, M] similarity scores (2<q,x> - ||x||^2 for l2; <q,x> otherwise)."""
-    dots = q.astype(jnp.float32) @ x.astype(jnp.float32).T
+    dots = jnp.matmul(q.astype(jnp.float32), x.astype(jnp.float32).T,
+                      precision=HIGHEST)
     if metric == "l2":
         return 2.0 * dots - xsq.astype(jnp.float32)[None, :]
     return dots
@@ -43,7 +45,8 @@ def ref_gather_scores(
     """[B, C] scores of each query against its own gathered candidates."""
     rows = table[ids]                       # [B, C, d]
     dots = jnp.einsum(
-        "bcd,bd->bc", rows.astype(jnp.float32), q.astype(jnp.float32)
+        "bcd,bd->bc", rows.astype(jnp.float32), q.astype(jnp.float32),
+        precision=HIGHEST,
     )
     if metric == "l2":
         return 2.0 * dots - tsq[ids].astype(jnp.float32)
@@ -61,7 +64,8 @@ def ref_gather_scores_q8(
     l2 → s·(2·<codes,q> − s·Σcodes²), ip/cos → s·<codes,q> (DESIGN.md §10)."""
     rows = codes[ids].astype(jnp.float32)   # [B, C, d]
     s = scales[ids].astype(jnp.float32)     # [B, C]
-    dots = jnp.einsum("bcd,bd->bc", rows, q.astype(jnp.float32))
+    dots = jnp.einsum("bcd,bd->bc", rows, q.astype(jnp.float32),
+                      precision=HIGHEST)
     if metric == "l2":
         return s * (2.0 * dots - s * jnp.sum(rows * rows, axis=-1))
     return s * dots

@@ -8,6 +8,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any, Callable
 
 import jax
@@ -307,7 +308,14 @@ def _ipgm_cell(spec: reg.ArchSpec, shape: str, mesh) -> Cell:
         flops = cell.sizes["batch"] * cfg.eff_d_in * per_q
     else:
         fn = ann.make_insert_step(dp, mesh)
-        args = (state_sds, inputs["vecs"], inputs["route"], key_sds)
+        # round-robin routing: each shard's block holds its share of rows
+        n_shards = math.prod(mesh.shape[a] for a in dp.axes)
+        batch, dim = inputs["vecs"].shape
+        share = -(-batch // n_shards)
+        args = (state_sds,
+                jax.ShapeDtypeStruct((n_shards, share, dim), jnp.float32),
+                jax.ShapeDtypeStruct((n_shards, share), jnp.int32),
+                jax.ShapeDtypeStruct((n_shards, share), jnp.bool_), key_sds)
         flops = cell.sizes["batch"] * per_q
     return Cell(spec.arch_id, shape, cell.kind, fn, args,
                 {"model_flops": int(flops)}, param_specs=state_spec)

@@ -27,8 +27,6 @@ from pathlib import Path
 
 import jax
 
-from repro import compat
-
 MANIFEST = Path(__file__).resolve().parents[3] / "results" / "dryrun_manifest.json"
 
 _COLL_RE = re.compile(
@@ -94,7 +92,7 @@ def run_cell(arch_id: str, shape: str, mesh_kind: str) -> dict:
 
     mesh = make_production_mesh(multi_pod=(mesh_kind == "multi"))
     t0 = time.time()
-    with compat.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         cell = build_cell(arch_id, shape, mesh)
         lowered = cell.fn.lower(*cell.args)
         t_lower = time.time() - t0

@@ -12,8 +12,11 @@ from the session's flush-based ``PhaseTimers``.
 from __future__ import annotations
 
 import argparse
+import os
 import time
+from pathlib import Path
 
+import jax
 import numpy as np
 
 from repro.core import (
@@ -24,6 +27,24 @@ from repro.core import (
     TieredSession,
 )
 from repro.data.workload import make_workload
+
+# a fixed directory in the checkout: each run looks where the last one wrote
+CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Keep compiled programs across runs; returns the cache directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, places the cache (JAX reads the
+    variable itself, so nothing is set here); otherwise the cache lives in
+    ``.jax_cache/`` at the root of the checkout. Call before the first
+    compile.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))
+    return str(CACHE_DIR)
 
 
 def serve_online(
@@ -43,13 +64,17 @@ def serve_online(
     recover: bool = False,
     tiered: bool = False,
     fresh_capacity: int | None = None,
-) -> list[dict]:
+) -> tuple[Session | TieredSession, float, list[dict]]:
+    """Build the index from the workload's base set, then run its
+    maintenance steps. Returns (session, build seconds, per-step records).
+    """
     wl = make_workload(
         dataset, n_base=n_base, n_steps=n_steps, batch_size=batch_size,
         n_queries=n_queries, pattern="random", seed=seed,
     )
     dim = wl.base.shape[1]
-    capacity = n_base + n_steps * batch_size + 16
+    # the power-of-two tier that holds the whole stream (DESIGN.md §9 tiers)
+    capacity = 1 << (n_base + n_steps * batch_size + 16 - 1).bit_length()
     maintenance = MaintenanceParams(strategy=strategy)
     if tiered:
         # two-tier serving (DESIGN.md §12): inserts land in a small fresh
@@ -94,6 +119,7 @@ def serve_online(
         # every acknowledged op survives a crash up to the fsync policy
         session = Session(params, seed=seed, checkpoint_dir=checkpoint_dir)
 
+    build_s = 0.0
     if recover and session._op_counter > 0:
         # the recovered timeline already contains the base build (and
         # whatever stream prefix was acknowledged before the crash); the
@@ -114,7 +140,8 @@ def serve_online(
             ids = session.insert(wl.base).result()
             id_map = list(np.asarray(ids))   # pool position → graph id
         session.flush()
-        print(f"  built in {time.perf_counter() - t0:.1f}s")
+        build_s = time.perf_counter() - t0
+        print(f"  built in {build_s:.1f}s")
 
     records = []
     for step in range(wl.n_steps):
@@ -148,7 +175,7 @@ def serve_online(
             f"({rec['update_ops_per_s']:.0f} ops/s) alive={rec['n_alive']}"
         )
     print("session timers:", session.flush().to_dict())
-    return records
+    return session, build_s, records
 
 
 def main() -> None:
@@ -170,6 +197,7 @@ def main() -> None:
     ap.add_argument("--fresh-capacity", type=int, default=None,
                     help="fresh-tier slot count (tiered mode only)")
     args = ap.parse_args()
+    enable_compile_cache()
     serve_online(
         dataset=args.dataset, strategy=args.strategy, n_base=args.scale,
         n_steps=args.steps, batch_size=max(args.scale // 10, 10),

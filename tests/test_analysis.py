@@ -37,8 +37,7 @@ def test_agrees_with_xla_on_loop_free():
         return jnp.tanh(a @ b) @ b
 
     ours = cost_of(f, A, A, io_bytes=False).flops
-    from repro import compat
-    xla = compat.compiled_cost_analysis(jax.jit(f).lower(A, A).compile())["flops"]
+    xla = jax.jit(f).lower(A, A).compile().cost_analysis()["flops"]
     assert abs(ours - xla) / xla < 0.02
 
 

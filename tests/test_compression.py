@@ -52,8 +52,6 @@ def test_compressed_psum_vs_fp32_psum_small_trees():
     (the int8 reduction itself is exact), on a small multi-leaf tree."""
     from repro.distributed.compression import compressed_psum
 
-    from repro import compat
-
     mesh = jax.make_mesh((1,), ("d",))
     rng = np.random.default_rng(3)
     tree = {
@@ -68,7 +66,7 @@ def test_compressed_psum_vs_fp32_psum_small_trees():
         return comp, exact
 
     P = jax.sharding.PartitionSpec
-    comp, exact = jax.jit(compat.shard_map(
+    comp, exact = jax.jit(jax.shard_map(
         f, mesh=mesh, in_specs=(P(),), out_specs=(P(), P()),
         check_vma=False,
     ))(tree)
@@ -120,8 +118,7 @@ def test_compressed_psum_multi_device():
     def f(grads):
         return compressed_psum(grads, jax.random.PRNGKey(0), "d")
 
-    from repro import compat
-    out = jax.jit(compat.shard_map(
+    out = jax.jit(jax.shard_map(
         f, mesh=mesh,
         in_specs=(jax.sharding.PartitionSpec(),),
         out_specs=jax.sharding.PartitionSpec(),

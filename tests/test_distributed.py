@@ -12,9 +12,8 @@ import pytest
 SCRIPT = r"""
 import json
 import numpy as np, jax, jax.numpy as jnp
-from repro import compat
 from repro.distributed.ann import (DistParams, init_sharded_state,
-                                   make_query_step, make_insert_step,
+                                   make_query_step, distributed_insert,
                                    make_delete_step)
 from repro.core.params import IndexParams, SearchParams
 
@@ -27,10 +26,9 @@ state = init_sharded_state(dp, mesh)
 rng = np.random.default_rng(0)
 X = rng.normal(size=(200, 16)).astype(np.float32)
 route = np.arange(200).astype(np.int32)
-with compat.use_mesh(mesh):
-    st, gids = make_insert_step(dp, mesh)(state, jnp.asarray(X),
-                                          jnp.asarray(route),
-                                          jax.random.PRNGKey(0))
+with jax.set_mesh(mesh):
+    st, gids = distributed_insert(state, X, route, jax.random.PRNGKey(0),
+                                  dp, mesh)
     g = np.asarray(gids)
     out['n_inserted'] = int((g >= 0).sum())
     out['gids_unique'] = len(set(g.tolist())) == 200
@@ -133,11 +131,10 @@ with compat.use_mesh(mesh):
     # multi-pod replica mesh
     mesh3 = jax.make_mesh((2, 2, 2), ('pod', 'data', 'model'))
     dp3 = DistParams(index=ip, pod_axis='pod')
-with compat.use_mesh(mesh3):
+with jax.set_mesh(mesh3):
     st3 = init_sharded_state(dp3, mesh3)
-    st3, gids3 = make_insert_step(dp3, mesh3)(st3, jnp.asarray(X[:80]),
-                                              jnp.asarray(route[:80]),
-                                              jax.random.PRNGKey(0))
+    st3, gids3 = distributed_insert(st3, X[:80], route[:80],
+                                    jax.random.PRNGKey(0), dp3, mesh3)
     ids3, _ = make_query_step(dp3, mesh3)(st3, Q[:8], jax.random.PRNGKey(1))
     out['multipod_inserted'] = int((np.asarray(gids3) >= 0).sum())
     out['multipod_results_valid'] = bool((np.asarray(ids3)[:, 0] >= 0).all())
@@ -182,3 +179,28 @@ def test_sharded_index_8dev():
     assert out["fault_crash_fired"], "armed sharded crash point must fire"
     assert out["multipod_inserted"] == 80
     assert out["multipod_results_valid"]
+
+
+@pytest.mark.parametrize("route", ["round_robin", "skewed", "empty"])
+def test_shard_blocks_groups_rows_by_owner(route):
+    """Host grouping behind the sharded insert: every row lands in its
+    owner's block, in arrival order, with its batch index alongside."""
+    import numpy as np
+
+    from repro.distributed.ann import shard_blocks
+
+    rng = np.random.default_rng(0)
+    n = 0 if route == "empty" else 37
+    vecs = rng.normal(size=(n, 5)).astype(np.float32)
+    r = (np.arange(n) if route == "round_robin"
+         else rng.choice([1, 1, 1, 6], size=n))
+    blocks, rows, valid, pos = shard_blocks(vecs, r, 4)
+    m = blocks.shape[1]
+    assert m & (m - 1) == 0 and m >= max(np.bincount(r % 4, minlength=4))
+    assert blocks.shape == (4, m, 5) and rows.shape == valid.shape == (4, m)
+    np.testing.assert_array_equal(blocks.reshape(-1, 5)[pos], vecs)
+    np.testing.assert_array_equal(rows.reshape(-1)[pos], np.arange(n))
+    np.testing.assert_array_equal(pos // m, r % 4)
+    assert valid.sum() == n and valid.reshape(-1)[pos].all()
+    for s in range(4):   # arrival order within each shard
+        assert (np.diff(rows[s][valid[s]]) > 0).all()

@@ -261,3 +261,154 @@ def test_query_ragged_chunk_padding_matches_full():
         t[0] in set(np.asarray(r).tolist()) for r, t in zip(ids, np.asarray(true_ids))
     ])
     assert agree > 0.8
+
+
+# ---------------------------------------------------------------------------
+# apply_row_updates vs the dense capacity-wide formula it replaced
+# ---------------------------------------------------------------------------
+
+def _dense_apply_row_updates(state, us, new_rows, valid):
+    """The pre-rewrite applier, kept verbatim as the bit-exact oracle: it
+    patches radj with [capacity, d_in, d_out] and [capacity, d_in]
+    temporaries (removals tested against every reverse entry, additions
+    grouped by a searchsorted over every slot)."""
+    import dataclasses
+
+    cap, d_out, d_in = state.capacity, state.d_out, state.d_in
+    R = us.shape[0]
+    valid = valid & (us != NULL)
+    su = jnp.where(valid, us, 0)
+    wsu = jnp.where(valid, us, cap)
+    old_rows = jnp.where(valid[:, None], state.adj[su], NULL)
+    new_rows = jnp.where(valid[:, None], new_rows, NULL)
+
+    row_of = jnp.full((cap + 1,), -1, jnp.int32).at[wsu].set(
+        jnp.arange(R, dtype=jnp.int32), mode="drop"
+    )[:cap]
+    rv = state.radj
+    r_idx = jnp.where(rv != NULL, row_of[jnp.maximum(rv, 0)], -1)
+    nr = new_rows[jnp.maximum(r_idx, 0)]
+    still = jnp.any(nr == jnp.arange(cap)[:, None, None], axis=2)
+    radj1 = jnp.where((r_idx >= 0) & ~still, NULL, rv)
+
+    add_m = (new_rows != NULL) & ~jnp.any(
+        new_rows[:, :, None] == old_rows[:, None, :], axis=2
+    )
+    src = jnp.broadcast_to(su[:, None], (R, d_out)).reshape(-1)
+    dst = new_rows.reshape(-1)
+    add_flat = add_m.reshape(-1)
+    E = dst.shape[0]
+    key_dst = jnp.where(add_flat, dst, cap)
+    order = jnp.argsort(key_dst, stable=True)
+    sorted_key = key_dst[order]
+    sorted_src = src[order]
+    vids = jnp.arange(cap, dtype=key_dst.dtype)
+    start = jnp.searchsorted(sorted_key, vids, side="left")
+    end = jnp.searchsorted(sorted_key, vids, side="right")
+    idx = start[:, None] + jnp.arange(d_in)[None, :]
+    add_rows = jnp.where(
+        idx < end[:, None], sorted_src[jnp.clip(idx, 0, E - 1)], NULL
+    )
+
+    holes = d_in - jnp.sum(radj1 != NULL, axis=1)
+    ar = add_rows[jnp.clip(new_rows, 0, cap - 1)]
+    match = ar == su[:, None, None]
+    past_holes = (
+        jnp.arange(d_in)[None, None, :]
+        >= holes[jnp.clip(new_rows, 0, cap - 1)][:, :, None]
+    )
+    refused = add_m & (
+        jnp.any(match & past_holes, axis=2) | ~jnp.any(match, axis=2)
+    )
+    final_rows = jnp.where(refused, NULL, new_rows)
+    adj = state.adj.at[wsu].set(final_rows, mode="drop")
+
+    isnull = radj1 == NULL
+    hole_rank = jnp.cumsum(isnull.astype(jnp.int32), axis=1) - 1
+    fill = jnp.take_along_axis(
+        add_rows, jnp.clip(hole_rank, 0, d_in - 1), axis=1
+    )
+    radj2 = jnp.where(isnull, fill, radj1)
+    touch = state.touch.at[wsu].set(state.tclock, mode="drop")
+    return dataclasses.replace(
+        state, adj=adj, radj=radj2, touch=touch, tclock=state.tclock + 1
+    )
+
+
+def _rewrite_batch(st, rng, n_rows, *, keep_old, hubs=None, R=None):
+    """Sanitized rewrite rows: unique present targets, no self edges, valid
+    rows unique, plus masked and NULL lanes. ``keep_old`` carries part of
+    each old row over (permuted); ``hubs`` concentrates the new targets on
+    a few destinations to force in-degree pressure."""
+    present = np.flatnonzero(np.asarray(st.present))
+    adj = np.asarray(st.adj)
+    d_out = st.d_out
+    R = R or n_rows + 3
+    us = np.full((R,), NULL, np.int32)
+    rows = np.full((R, d_out), NULL, np.int32)
+    valid = np.zeros((R,), bool)
+    picked = rng.choice(present, size=n_rows, replace=False)
+    for r, u in enumerate(picked):
+        pool = hubs if hubs is not None else present
+        pool = np.setdiff1d(pool, [u])
+        k = int(rng.integers(1, d_out + 1))
+        row = []
+        if keep_old:
+            old = adj[u][adj[u] != NULL]
+            row = list(rng.permutation(old)[: int(rng.integers(0, len(old) + 1))])
+        fresh = rng.permutation(np.setdiff1d(pool, row))
+        row = (row + list(fresh))[:k]
+        rows[r, : len(row)] = row
+        us[r] = u
+        valid[r] = True
+    # a masked lane with a real id, and NULL lanes (must both be no-ops)
+    us[n_rows] = int(rng.choice(np.setdiff1d(present, picked)))
+    rows[n_rows] = rows[0]
+    order = rng.permutation(R)
+    return (jnp.asarray(us[order]), jnp.asarray(rows[order]),
+            jnp.asarray(valid[order]))
+
+
+@pytest.mark.parametrize("case", ["no_pressure", "pressure", "rewrite_existing",
+                                  "grown_capacity"])
+def test_apply_row_updates_matches_dense_oracle(case):
+    """The R·d_out-scaled applier is array-equal to the dense formula on
+    adj, radj, touch and tclock — refusals under in-degree pressure
+    included — over several seeded batches per case."""
+    from repro.core.graph import apply_row_updates, grow_state
+
+    d_in = {"pressure": 6}.get(case, 24)
+    p = _params(d_in=d_in, capacity=64)
+    st = _fresh(p)
+    rng = np.random.default_rng({"no_pressure": 1, "pressure": 2,
+                                 "rewrite_existing": 3,
+                                 "grown_capacity": 4}[case])
+    for lo in (0, 20, 40):
+        st, _ = insert_mod.insert_batch(
+            st, jnp.asarray(rng.normal(size=(20, p.dim)).astype(np.float32)),
+            jnp.ones((20,), bool), jax.random.PRNGKey(lo), p,
+        )
+    if case == "grown_capacity":
+        st = grow_state(st, 97)
+    assert not check_invariants(st)
+    n_refused = 0
+    for it in range(4):
+        hubs = (np.flatnonzero(np.asarray(st.present))[:8]
+                if case == "pressure" else None)
+        us, rows, valid = _rewrite_batch(
+            st, rng, 12, keep_old=case == "rewrite_existing", hubs=hubs)
+        want = jax.jit(_dense_apply_row_updates)(st, us, rows, valid)
+        got = jax.jit(apply_row_updates)(st, us, rows, valid)
+        for f in ("adj", "radj", "touch", "tclock"):
+            np.testing.assert_array_equal(
+                np.asarray(getattr(got, f)), np.asarray(getattr(want, f)),
+                err_msg=f"{case} batch {it}: {f}")
+        v = np.asarray(valid)
+        n_refused += int(np.sum(np.asarray(rows)[v] != NULL)) - int(
+            np.sum(np.asarray(got.adj)[np.asarray(us)[v]] != NULL))
+        assert not check_invariants(got)
+        st = got
+    if case == "pressure":
+        assert n_refused > 0, "pressure case never refused an addition"
+    else:
+        assert n_refused == 0
