@@ -49,13 +49,12 @@ entry of the maintenance-op registry (``core/maint.py``, DESIGN.md §14);
 """
 from __future__ import annotations
 
-import time
-
 import numpy as np
 
 from repro.core import ops as ops_mod
 from repro.core.graph import NULL, next_capacity_tier
 from repro.core.session import OpHandle
+from repro.core.trace import span
 from repro.testing import faults
 
 # phase tags, in execution order
@@ -107,14 +106,14 @@ class StreamingMerge:
         """Perform one bounded chunk of merge work. Returns ``done``."""
         if self.phase == DONE:
             return True
-        t0 = time.perf_counter()
-        if self.phase == COMPACT:
-            self._compact_step()
-        elif self.phase == DRAIN:
-            self._drain_step()
-        elif self.phase == SWAP:
-            self._swap_step()
-        self.owner.timers.merge_s += time.perf_counter() - t0
+        with span("merge.step", self.owner.timers, "merge_s",
+                  phase=self.phase):
+            if self.phase == COMPACT:
+                self._compact_step()
+            elif self.phase == DRAIN:
+                self._drain_step()
+            elif self.phase == SWAP:
+                self._swap_step()
         return self.phase == DONE
 
     def run(self) -> None:

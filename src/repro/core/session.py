@@ -93,6 +93,7 @@ from repro.core.graph import (
 )
 from repro.core.ops import OP_DELETE, OP_INSERT, OP_QUERY
 from repro.core.params import IndexParams
+from repro.core.trace import span
 from repro.testing import faults
 
 
@@ -104,7 +105,12 @@ class PhaseTimers:
     dispatch the device wait lands in ``flush_s`` instead, and ``wall_s``
     tracks end-to-end busy wall-clock (first dispatch of a window → the
     flush closing it). The legacy per-op facade flushes after every op, so
-    for it ``wall_s`` ≈ the old synchronous per-op totals.
+    for it ``wall_s`` ≈ the old synchronous per-op totals. ``enqueue_s`` is
+    the part of ``query_s``/``insert_s``/``delete_s`` spent in the op's
+    device calls (key fold, batch transfer, step call), where the host
+    blocks until the previous step releases the donated state;
+    ``total()`` does not add it again. Each timed block is also an
+    ``ipgm.`` profiler span (``core/trace.py``).
     """
 
     query_s: float = 0.0
@@ -117,6 +123,7 @@ class PhaseTimers:
     refine_s: float = 0.0        # §15 background refinement passes
     flush_s: float = 0.0
     wall_s: float = 0.0
+    enqueue_s: float = 0.0       # in the ops' device calls (see above)
     n_queries: int = 0
     n_inserts: int = 0
     n_deletes: int = 0
@@ -202,6 +209,19 @@ class OpHandle:
             if self._on_done is not None:
                 self._on_done(self)
 
+    def _to_host(self, take=None) -> list:
+        """Each chunk in order: wait for its step (``handle.wait``), then
+        copy what ``take(ids, scores, n_valid)`` picks of its results to the
+        host (``handle.fetch``)."""
+        out = []
+        for c, (ids, scores, nv) in enumerate(self._chunks):
+            with span("handle.wait", op=self.op, chunk=c):
+                jax.block_until_ready((ids, scores))
+            if take is not None:
+                with span("handle.fetch", op=self.op, chunk=c):
+                    out.append(take(ids, scores, nv))
+        return out
+
     def result(self):
         """Block until this op's results are on host.
 
@@ -212,40 +232,37 @@ class OpHandle:
         refine      → ids i32[n] of the re-wired slots
         """
         try:
+            if self.op == "delete":
+                self._to_host()
+                return None
+            if self.op == "query":
+                k = self.k
+                parts = self._to_host(lambda i, s, nv: (
+                    np.asarray(i)[:nv, :k], np.asarray(s)[:nv, :k]))
+                if not parts:
+                    return (np.full((0, k), NULL, np.int32),
+                            np.full((0, k), -np.inf, np.float32))
+                with span("handle.fetch", op=self.op):
+                    return (np.concatenate([p[0] for p in parts]),
+                            np.concatenate([p[1] for p in parts]))
+            # insert/consolidate/refine: slot ids ride in column 0 of the
+            # result block
+            parts = self._to_host(lambda i, s, nv: np.asarray(i)[:nv, 0])
+            if not parts:
+                out = np.zeros((0,), np.int32)
+            else:
+                with span("handle.fetch", op=self.op):
+                    out = np.concatenate(parts)
             if self.op == "insert" and self.total_rows is not None:
-                out = (np.concatenate(
-                    [np.asarray(i)[:nv, 0] for i, _, nv in self._chunks]
-                ) if self.n else np.zeros((0,), np.int32))
                 full = np.full((self.total_rows,), NULL, np.int32)
                 full[self.row_map] = out
                 return full
-            if self.op == "delete" or self.n == 0:
-                if self.op == "query":
-                    return (np.full((0, self.k), NULL, np.int32),
-                            np.full((0, self.k), -np.inf, np.float32))
-                if self.op in ("insert", "consolidate", "refine"):
-                    return np.zeros((0,), np.int32)
-                for ids, _, _ in self._chunks:
-                    jax.block_until_ready(ids)
-                return None
-            if self.op == "query":
-                ids = np.concatenate(
-                    [np.asarray(i)[:nv, : self.k] for i, _, nv in self._chunks]
-                )
-                scores = np.concatenate(
-                    [np.asarray(s)[:nv, : self.k] for _, s, nv in self._chunks]
-                )
-                return ids, scores
-            # insert/consolidate: slot ids ride in column 0 of the result block
-            return np.concatenate(
-                [np.asarray(i)[:nv, 0] for i, _, nv in self._chunks]
-            )
+            return out
         finally:
             self._finish()
 
     def block(self) -> None:
-        for ids, scores, _ in self._chunks:
-            jax.block_until_ready((ids, scores))
+        self._to_host()
         self._finish()
 
 
@@ -445,8 +462,9 @@ class Session:
         cseq = (getattr(self, mop.counter_attr)
                 if mop is not None and mop.counter_attr is not None
                 else self._consolidate_counter)
-        self._journal.append(code, seq=self._op_counter, cseq=cseq,
-                             payload=payload, ids=ids, aux=aux)
+        with span("journal.append", code=code):
+            self._journal.append(code, seq=self._op_counter, cseq=cseq,
+                                 payload=payload, ids=ids, aux=aux)
         faults.crash_point("post-journal-append")
 
     # -- dispatch core -----------------------------------------------------
@@ -461,7 +479,12 @@ class Session:
                 raise ValueError(
                     f"OP_{mop.name.upper()} is not a stream op; "
                     f"use Session.{mop.name}()")
-        key = self._op_key()  # consumed even for empty ops: stable chain
+        # Every device call of the op sits in an ``enqueue`` span: once the
+        # runtime holds as many steps in flight as it takes, whichever call
+        # comes next (the key fold, the batch's transfer, the step call)
+        # blocks until the previous step releases the donated state.
+        with span("session.enqueue", self.timers, "enqueue_s"):
+            key = self._op_key()  # consumed even for empty ops: stable chain
         n = arr.shape[0]
         if n == 0:  # no device work: don't arm the busy-wall window
             h = OpHandle(ops_mod.OP_NAMES[op_code], 0,
@@ -472,28 +495,31 @@ class Session:
             self._window_t0 = time.perf_counter()
         static_op = None if self.unified_dispatch else op_code
         is_delete = op_code == OP_DELETE
+        name = ops_mod.OP_NAMES[op_code]
         chunks = []
         for ci, lo in enumerate(range(0, n, chunk)):
-            part = arr[lo:lo + chunk]
-            batch = ops_mod.make_op(
-                op_code, chunk, self.params.dim,
-                payload=None if is_delete else part,
-                ids=part if is_delete else None,
-                offset=lo,
-            )
-            # deletes decorrelate multi-chunk repair searches by chunk index
-            # (their lane folds are chunk-local); query/insert fold global
-            # stream indices via `offset` instead, for chunking invariance
-            ckey = jax.random.fold_in(key, ci) if fold_chunk_key else key
-            self._state, ids, scores = ops_mod.apply_ops_step(
-                self._state, batch, ckey, self.params, self.strategy,
-                static_op=static_op,
-            )
-            chunks.append((ids, scores, part.shape[0]))
-        handle = OpHandle(
-            ops_mod.OP_NAMES[op_code], n, self.params.search.pool_size,
-            chunks, on_done=self._handle_done,
-        )
+            with span("session.step", op=name, chunk=ci):
+                part = arr[lo:lo + chunk]
+                with span("session.enqueue", self.timers, "enqueue_s"):
+                    batch = ops_mod.make_op(
+                        op_code, chunk, self.params.dim,
+                        payload=None if is_delete else part,
+                        ids=part if is_delete else None,
+                        offset=lo,
+                    )
+                    # deletes decorrelate multi-chunk repair searches by
+                    # chunk index (their lane folds are chunk-local);
+                    # query/insert fold global stream indices via `offset`
+                    # instead, for chunking invariance
+                    ckey = (jax.random.fold_in(key, ci) if fold_chunk_key
+                            else key)
+                    self._state, ids, scores = ops_mod.apply_ops_step(
+                        self._state, batch, ckey, self.params, self.strategy,
+                        static_op=static_op,
+                    )
+                chunks.append((ids, scores, part.shape[0]))
+        handle = OpHandle(name, n, self.params.search.pool_size, chunks,
+                          on_done=self._handle_done)
         self._pending.append(handle)
         self.timers.n_ops += 1
         return handle
@@ -523,10 +549,9 @@ class Session:
         # queries don't mutate state but DO consume an op key, so replay must
         # know they happened — a count-only record keeps the journal cheap
         self._journal_append(OP_QUERY, aux={"n": int(q.shape[0])})
-        t0 = time.perf_counter()
-        h = self._dispatch(OP_QUERY, q, chunk or self.chunk)
-        h.k = min(k, self.params.search.pool_size)
-        self.timers.query_s += time.perf_counter() - t0
+        with span("session.query", self.timers, "query_s"):
+            h = self._dispatch(OP_QUERY, q, chunk or self.chunk)
+            h.k = min(k, self.params.search.pool_size)
         self.timers.n_queries += q.shape[0]
         return h
 
@@ -560,14 +585,13 @@ class Session:
         # trigger does), so PhaseTimers.total() never double-counts
         if v.shape[0]:
             self._ensure_room(v.shape[0])
-        t0 = time.perf_counter()
-        h = self._dispatch(OP_INSERT, v, chunk or
-                           self.params.maintenance.insert_chunk)
-        if keep is not None:
-            h.row_map, h.total_rows = keep, total
-        self._free_hint = max(self._free_hint - v.shape[0], 0)
-        self._refine_wear += v.shape[0]
-        self.timers.insert_s += time.perf_counter() - t0
+        with span("session.insert", self.timers, "insert_s"):
+            h = self._dispatch(OP_INSERT, v, chunk or
+                               self.params.maintenance.insert_chunk)
+            if keep is not None:
+                h.row_map, h.total_rows = keep, total
+            self._free_hint = max(self._free_hint - v.shape[0], 0)
+            self._refine_wear += v.shape[0]
         self.timers.n_inserts += v.shape[0]
         return h
 
@@ -584,10 +608,9 @@ class Session:
         # effective width is part of the op's identity — journal it
         self._journal_append(OP_DELETE, ids=arr,
                              aux={"chunk": int(eff_chunk)})
-        t0 = time.perf_counter()
-        h = self._dispatch(OP_DELETE, arr, eff_chunk,
-                           fold_chunk_key=True)
-        self.timers.delete_s += time.perf_counter() - t0
+        with span("session.delete", self.timers, "delete_s"):
+            h = self._dispatch(OP_DELETE, arr, eff_chunk,
+                               fold_chunk_key=True)
         self.timers.n_deletes += arr.shape[0]
         self._refine_wear += arr.shape[0]
         if self.strategy == "mask":
@@ -635,50 +658,50 @@ class Session:
             self._journal_append(ops_mod.JR_CONSOLIDATE,
                                  aux={"strategy": strategy, "chunk": chunk})
         faults.crash_point("pre-consolidate")
-        t0 = time.perf_counter()
-        n_masked = (int(jnp.sum(self._state.masked))
-                    if _n_masked is None else int(_n_masked))
-        if n_masked == 0:
-            self._masked_hint = 0
-            self.timers.consolidate_s += time.perf_counter() - t0
-            return 0
-        if self._window_t0 is None:
-            self._window_t0 = time.perf_counter()
-        mp = self.params.maintenance
-        chunk = int(chunk) if chunk else (mp.consolidate_chunk
-                                          or mp.delete_chunk)
-        params = self.params
-        if strategy is not None and strategy != mp.consolidate_strategy:
-            params = dataclasses.replace(
-                self.params,
-                maintenance=dataclasses.replace(
-                    mp, consolidate_strategy=strategy),
+        with span("session.consolidate", self.timers, "consolidate_s"):
+            n_masked = (int(jnp.sum(self._state.masked))
+                        if _n_masked is None else int(_n_masked))
+            if n_masked == 0:
+                self._masked_hint = 0
+                return 0
+            if self._window_t0 is None:
+                self._window_t0 = time.perf_counter()
+            mp = self.params.maintenance
+            chunk = int(chunk) if chunk else (mp.consolidate_chunk
+                                              or mp.delete_chunk)
+            params = self.params
+            if strategy is not None and strategy != mp.consolidate_strategy:
+                params = dataclasses.replace(
+                    self.params,
+                    maintenance=dataclasses.replace(
+                        mp, consolidate_strategy=strategy),
+                )
+            # always static-dispatched (ops.py): maintenance passes are
+            # host-initiated, so the mixed-stream switch never carries this
+            # branch and only consolidating sessions compile it
+            static_op = ops_mod.OP_CONSOLIDATE
+            chunks = []
+            # the op is operand-free: one encoded batch serves every drain
+            # step
+            batch = ops_mod.make_op(ops_mod.OP_CONSOLIDATE, chunk,
+                                    self.params.dim)
+            for lo in range(0, n_masked, chunk):
+                self._state, ids, scores = ops_mod.apply_ops_step(
+                    self._state, batch, self._maint_key(maint.CONSOLIDATE),
+                    params, self.strategy, static_op=static_op,
+                )
+                chunks.append((ids, scores, min(chunk, n_masked - lo)))
+            handle = OpHandle(
+                "consolidate", n_masked, self.params.search.pool_size, chunks,
+                on_done=self._handle_done,
             )
-        # always static-dispatched (ops.py): maintenance passes are
-        # host-initiated, so the mixed-stream switch never carries this
-        # branch and only consolidating sessions compile it
-        static_op = ops_mod.OP_CONSOLIDATE
-        chunks = []
-        # the op is operand-free: one encoded batch serves every drain step
-        batch = ops_mod.make_op(ops_mod.OP_CONSOLIDATE, chunk, self.params.dim)
-        for lo in range(0, n_masked, chunk):
-            self._state, ids, scores = ops_mod.apply_ops_step(
-                self._state, batch, self._maint_key(maint.CONSOLIDATE),
-                params, self.strategy, static_op=static_op,
-            )
-            chunks.append((ids, scores, min(chunk, n_masked - lo)))
-        handle = OpHandle(
-            "consolidate", n_masked, self.params.search.pool_size, chunks,
-            on_done=self._handle_done,
-        )
-        # the int return keeps the legacy contract; the compacted slot ids
-        # stay reachable through this handle until consumed/flushed
-        self.last_consolidate_handle = handle
-        self._pending.append(handle)
-        self.timers.n_ops += 1
-        self.timers.n_consolidations += 1
-        self.timers.n_consolidated += n_masked
-        self.timers.consolidate_s += time.perf_counter() - t0
+            # the int return keeps the legacy contract; the compacted slot
+            # ids stay reachable through this handle until consumed/flushed
+            self.last_consolidate_handle = handle
+            self._pending.append(handle)
+            self.timers.n_ops += 1
+            self.timers.n_consolidations += 1
+            self.timers.n_consolidated += n_masked
         self._masked_hint = 0
         self._present_floor = max(self._present_floor - n_masked, 0)
         self._free_hint += n_masked  # compacted slots return to the allocator
@@ -731,40 +754,39 @@ class Session:
                 aux={"n": None if n is None else int(n),
                      "chunk": None if chunk is None else int(chunk)})
         faults.crash_point("refine-begin")
-        t0 = time.perf_counter()
-        mp = self.params.maintenance
-        chunk = int(chunk) if chunk else (mp.refine_chunk or mp.insert_chunk)
-        n_alive = int(jnp.sum(self._state.alive))
-        n_target = min(chunk if n is None else int(n), n_alive)
-        self._refine_wear = 0  # any pass resets the odometer (incl. replay)
-        if n_target <= 0:
-            self.timers.refine_s += time.perf_counter() - t0
-            return 0
-        if self._window_t0 is None:
-            self._window_t0 = time.perf_counter()
-        # static-dispatched like every maintenance op: host-initiated, so
-        # the mixed-stream switch never carries this branch and only
-        # refining sessions compile it. Operand-free: one encoded batch
-        # serves every step of the pass.
-        batch = ops_mod.make_op(ops_mod.OP_REFINE, chunk, self.params.dim)
-        chunks = []
-        for lo in range(0, n_target, chunk):
-            self._state, ids, scores = ops_mod.apply_ops_step(
-                self._state, batch, self._maint_key(maint.REFINE),
-                self.params, self.strategy, static_op=ops_mod.OP_REFINE,
+        with span("session.refine", self.timers, "refine_s"):
+            mp = self.params.maintenance
+            chunk = int(chunk) if chunk else (mp.refine_chunk
+                                              or mp.insert_chunk)
+            n_alive = int(jnp.sum(self._state.alive))
+            n_target = min(chunk if n is None else int(n), n_alive)
+            self._refine_wear = 0  # any pass resets the odometer (replay too)
+            if n_target <= 0:
+                return 0
+            if self._window_t0 is None:
+                self._window_t0 = time.perf_counter()
+            # static-dispatched like every maintenance op: host-initiated, so
+            # the mixed-stream switch never carries this branch and only
+            # refining sessions compile it. Operand-free: one encoded batch
+            # serves every step of the pass.
+            batch = ops_mod.make_op(ops_mod.OP_REFINE, chunk, self.params.dim)
+            chunks = []
+            for lo in range(0, n_target, chunk):
+                self._state, ids, scores = ops_mod.apply_ops_step(
+                    self._state, batch, self._maint_key(maint.REFINE),
+                    self.params, self.strategy, static_op=ops_mod.OP_REFINE,
+                )
+                chunks.append((ids, scores, min(chunk, n_target - lo)))
+                faults.crash_point("refine-step")
+            handle = OpHandle(
+                "refine", n_target, self.params.search.pool_size, chunks,
+                on_done=self._handle_done,
             )
-            chunks.append((ids, scores, min(chunk, n_target - lo)))
-            faults.crash_point("refine-step")
-        handle = OpHandle(
-            "refine", n_target, self.params.search.pool_size, chunks,
-            on_done=self._handle_done,
-        )
-        self.last_refine_handle = handle
-        self._pending.append(handle)
-        self.timers.n_ops += 1
-        self.timers.n_refines += 1
-        self.timers.n_refined += n_target
-        self.timers.refine_s += time.perf_counter() - t0
+            self.last_refine_handle = handle
+            self._pending.append(handle)
+            self.timers.n_ops += 1
+            self.timers.n_refines += 1
+            self.timers.n_refined += n_target
         return n_target
 
     def _maybe_refine(self) -> int:
@@ -828,27 +850,27 @@ class Session:
         *armed* session enforces ``maintenance.max_capacity`` here too, so
         every tier it can ever save is one its own config restores.
         """
-        t0 = time.perf_counter()
         if new_capacity == self._state.capacity:
             return
-        ceiling = self.params.maintenance.max_capacity
-        if ceiling is not None and new_capacity > ceiling:
-            raise ValueError(
-                f"new_capacity {new_capacity} exceeds maintenance."
-                f"max_capacity {ceiling}")
-        if not _auto:
-            # explicit tier moves are journaled; auto-growth re-derives from
-            # the replayed op stream (same rationale as consolidate)
-            self._journal_append(ops_mod.JR_GROW,
-                                 aux={"new_capacity": int(new_capacity)})
-        faults.crash_point("pre-grow")
-        if self._window_t0 is None:
-            self._window_t0 = t0
-        grown = grow_state(self._state, new_capacity)
-        self._free_hint += grown.capacity - self._state.capacity
-        self._state = grown
-        self.timers.n_grows += 1
-        self.timers.grow_s += time.perf_counter() - t0
+        with span("session.grow", self.timers, "grow_s",
+                  capacity=int(new_capacity)):
+            ceiling = self.params.maintenance.max_capacity
+            if ceiling is not None and new_capacity > ceiling:
+                raise ValueError(
+                    f"new_capacity {new_capacity} exceeds maintenance."
+                    f"max_capacity {ceiling}")
+            if not _auto:
+                # explicit tier moves are journaled; auto-growth re-derives
+                # from the replayed op stream (same rationale as consolidate)
+                self._journal_append(ops_mod.JR_GROW,
+                                     aux={"new_capacity": int(new_capacity)})
+            faults.crash_point("pre-grow")
+            if self._window_t0 is None:
+                self._window_t0 = time.perf_counter()
+            grown = grow_state(self._state, new_capacity)
+            self._free_hint += grown.capacity - self._state.capacity
+            self._state = grown
+            self.timers.n_grows += 1
         faults.crash_point("post-grow")
 
     def flush(self) -> PhaseTimers:
@@ -881,26 +903,25 @@ class Session:
         exponential backoff; exhaustion re-raises, counted retries land in
         ``timers.n_retries``.
         """
-        t0 = time.perf_counter()
-        attempt = 0
-        while True:
-            try:
-                faults.transient_point("flush")
-                for h in list(self._pending):  # block() retires in place
-                    h.block()
-                jax.block_until_ready(self._state.adj)
-                break
-            except faults.TransientDispatchError:
-                if attempt >= self._flush_retries:
-                    raise
-                self.timers.n_retries += 1
-                time.sleep(self._flush_backoff_s * (2.0 ** attempt))
-                attempt += 1
-        self._pending.clear()
-        if self._journal is not None and self._journal.fsync_policy == "flush":
-            self._journal.sync()  # flush is the acknowledgement barrier
-        dt = time.perf_counter() - t0
-        self.timers.flush_s += dt
+        with span("session.flush", self.timers, "flush_s"):
+            attempt = 0
+            while True:
+                try:
+                    faults.transient_point("flush")
+                    for h in list(self._pending):  # block() retires in place
+                        h.block()
+                    jax.block_until_ready(self._state.adj)
+                    break
+                except faults.TransientDispatchError:
+                    if attempt >= self._flush_retries:
+                        raise
+                    self.timers.n_retries += 1
+                    time.sleep(self._flush_backoff_s * (2.0 ** attempt))
+                    attempt += 1
+            self._pending.clear()
+            if (self._journal is not None
+                    and self._journal.fsync_policy == "flush"):
+                self._journal.sync()  # flush is the acknowledgement barrier
         if self._window_t0 is not None:
             self.timers.wall_s += time.perf_counter() - self._window_t0
             self._window_t0 = None
@@ -922,22 +943,21 @@ class Session:
         rebuilding at the stale tier would silently shrink the index.
         """
         self.flush()
-        t0 = time.perf_counter()
-        live_cap = self._state.capacity
-        alive = np.asarray(self._state.alive)
-        vecs = np.asarray(self._state.vectors)[alive]
-        n = vecs.shape[0]
-        padded = np.zeros((live_cap, self.params.dim), vecs.dtype)
-        padded[:n] = vecs
-        valid = jnp.arange(live_cap) < n
-        self._state = rebuild.bulk_knn_build(
-            jnp.asarray(padded), valid, self._live_params()
-        )
-        jax.block_until_ready(self._state.adj)
-        self._masked_hint = 0
-        self._present_floor = n
-        self._free_hint = live_cap - n
-        self.timers.rebuild_s += time.perf_counter() - t0
+        with span("session.rebuild", self.timers, "rebuild_s"):
+            live_cap = self._state.capacity
+            alive = np.asarray(self._state.alive)
+            vecs = np.asarray(self._state.vectors)[alive]
+            n = vecs.shape[0]
+            padded = np.zeros((live_cap, self.params.dim), vecs.dtype)
+            padded[:n] = vecs
+            valid = jnp.arange(live_cap) < n
+            self._state = rebuild.bulk_knn_build(
+                jnp.asarray(padded), valid, self._live_params()
+            )
+            jax.block_until_ready(self._state.adj)
+            self._masked_hint = 0
+            self._present_floor = n
+            self._free_hint = live_cap - n
 
     # -- reporting ---------------------------------------------------------
     def ground_truth(self, queries, k: int):

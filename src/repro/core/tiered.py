@@ -71,6 +71,7 @@ from repro.core.session import (
     Session,
     params_fingerprint,
 )
+from repro.core.trace import span
 from repro.testing import faults
 
 _HARD_STRATEGIES = ("pure", "local", "global", "rwalk")
@@ -396,9 +397,10 @@ class TieredSession:
         # counter is the MERGE registry entry's ``counter_attr``
         # (core/maint.py) — the tiered tier registers exactly one
         # maintenance op, so every record snapshots it.
-        self._journal.append(code, seq=self._op_counter,
-                             cseq=getattr(self, maint.MERGE.counter_attr),
-                             payload=payload, ids=ids, aux=aux)
+        with span("journal.append", code=code):
+            self._journal.append(code, seq=self._op_counter,
+                                 cseq=getattr(self, maint.MERGE.counter_attr),
+                                 payload=payload, ids=ids, aux=aux)
         faults.crash_point("post-journal-append")
 
     # -- merge engine plumbing (DESIGN.md §12) -----------------------------
@@ -498,28 +500,27 @@ class TieredSession:
         k = min(k, self.params.search.pool_size)
         self._journal_append(OP_QUERY, aux={"n": int(q.shape[0])})
         self._op_counter += 1
-        t0 = time.perf_counter()
-        fkey = self._fresh_key(q)
-        hm = self._main.query(q, k=k)
-        # duplicates across tiers exist only while some item is "both"-
-        # resident mid-drain — snapshot the flag now, like the ext maps
-        # (the padded main map turns slot NULL (−1) into ext NULL by
-        # indexing). The snapshots are COW: handles share one frozen array
-        # until the next mutation invalidates it (``_ext_snap_dirty``).
-        fe = self._fext_snap
-        if fe is None:
-            fe = self._fext_snap = self._fm.ext.copy()
-        mp = self._mext_pad
-        if mp is None:
-            mp = self._mext_pad = np.append(self._mm.ext, np.int32(NULL))
-        both = (np.fromiter(self._both_set, np.int32,
-                            len(self._both_set))
-                if self._both_set else None)
-        h = TieredOpHandle("query", q.shape[0], k, (hm,),
-                           fresh_res=fkey, fresh_ext=fe, main_ext=mp,
-                           halved=self.params.metric == "l2",
-                           both=both)
-        self.timers.query_s += time.perf_counter() - t0
+        with span("tiered.query", self.timers, "query_s"):
+            fkey = self._fresh_key(q)
+            hm = self._main.query(q, k=k)
+            # duplicates across tiers exist only while some item is "both"-
+            # resident mid-drain — snapshot the flag now, like the ext maps
+            # (the padded main map turns slot NULL (−1) into ext NULL by
+            # indexing). The snapshots are COW: handles share one frozen array
+            # until the next mutation invalidates it (``_ext_snap_dirty``).
+            fe = self._fext_snap
+            if fe is None:
+                fe = self._fext_snap = self._fm.ext.copy()
+            mp = self._mext_pad
+            if mp is None:
+                mp = self._mext_pad = np.append(self._mm.ext, np.int32(NULL))
+            both = (np.fromiter(self._both_set, np.int32,
+                                len(self._both_set))
+                    if self._both_set else None)
+            h = TieredOpHandle("query", q.shape[0], k, (hm,),
+                               fresh_res=fkey, fresh_ext=fe, main_ext=mp,
+                               halved=self.params.metric == "l2",
+                               both=both)
         self.timers.n_queries += q.shape[0]
         self.timers.n_ops += 1
         return h
@@ -581,36 +582,35 @@ class TieredSession:
                 np.sum(self._fm.present) > 0
                 or self._active_merge is not None):
             self._merge_to_completion()
-        t0 = time.perf_counter()
-        free_ids = np.flatnonzero(~self._fm.present)
-        n_ok = min(nk, len(free_ids))
-        self.timers.n_refused += nk - n_ok
-        if nk:
-            sub.append(self._fresh.insert(vk))
-        else:
-            sub.append(self._fresh.insert(np.zeros((0, self.params.dim),
-                                                   np.float32)))
-        slots = free_ids[:n_ok].astype(np.int32)
-        self._fm.present[slots] = True
-        self._fm.ext[slots] = ek[:n_ok]
-        self._ext_snap_dirty()
-        # vector mirror for the exact fresh scan — what the device stores:
-        # verbatim f32 rows (cos: pre-normalized, the numpy twin of
-        # distances.normalize)
-        vstore = vk[:n_ok]
-        if self.params.metric == "cos":
-            vstore = vstore / np.sqrt(np.maximum(
-                np.sum(np.square(vstore), -1, keepdims=True), 1e-12))
-        self._fvec[slots] = vstore
-        self._fsqh[slots] = 0.5 * np.sum(np.square(vstore), axis=-1)
-        self._fbias[slots] = (-self._fsqh[slots]
-                              if self.params.metric == "l2" else 0.0)
-        for e, s in zip(ek[:n_ok], slots):
-            self._loc[int(e)] = ("fresh", int(s))
-        res = np.full((n,), NULL, np.int32)
-        live_idx = np.flatnonzero(live)
-        res[live_idx[:n_ok]] = ek[:n_ok]
-        self.timers.insert_s += time.perf_counter() - t0
+        with span("tiered.insert", self.timers, "insert_s"):
+            free_ids = np.flatnonzero(~self._fm.present)
+            n_ok = min(nk, len(free_ids))
+            self.timers.n_refused += nk - n_ok
+            if nk:
+                sub.append(self._fresh.insert(vk))
+            else:
+                sub.append(self._fresh.insert(np.zeros((0, self.params.dim),
+                                                       np.float32)))
+            slots = free_ids[:n_ok].astype(np.int32)
+            self._fm.present[slots] = True
+            self._fm.ext[slots] = ek[:n_ok]
+            self._ext_snap_dirty()
+            # vector mirror for the exact fresh scan — what the device
+            # stores: verbatim f32 rows (cos: pre-normalized, the numpy twin
+            # of distances.normalize)
+            vstore = vk[:n_ok]
+            if self.params.metric == "cos":
+                vstore = vstore / np.sqrt(np.maximum(
+                    np.sum(np.square(vstore), -1, keepdims=True), 1e-12))
+            self._fvec[slots] = vstore
+            self._fsqh[slots] = 0.5 * np.sum(np.square(vstore), axis=-1)
+            self._fbias[slots] = (-self._fsqh[slots]
+                                  if self.params.metric == "l2" else 0.0)
+            for e, s in zip(ek[:n_ok], slots):
+                self._loc[int(e)] = ("fresh", int(s))
+            res = np.full((n,), NULL, np.int32)
+            live_idx = np.flatnonzero(live)
+            res[live_idx[:n_ok]] = ek[:n_ok]
         self.timers.n_inserts += nk
         self.timers.n_ops += 1
         self._maybe_merge_start()
@@ -627,9 +627,8 @@ class TieredSession:
         self._journal_append(OP_DELETE, ids=arr)
         self._op_counter += 1
         self._pump()
-        t0 = time.perf_counter()
-        sub = self._delete_exts(arr)
-        self.timers.delete_s += time.perf_counter() - t0
+        with span("tiered.delete", self.timers, "delete_s"):
+            sub = self._delete_exts(arr)
         self.timers.n_deletes += arr.shape[0]
         self.timers.n_ops += 1
         self._maybe_merge_start()

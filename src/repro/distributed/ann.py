@@ -50,6 +50,7 @@ from repro.core.graph import (
     next_capacity_tier,
 )
 from repro.core.params import IndexParams
+from repro.core.trace import span
 from repro.testing import faults
 
 
@@ -420,12 +421,11 @@ class ShardedSession:
 
     def query(self, queries) -> tuple[jax.Array, jax.Array]:
         """Fan-out query → (global ids i32[B,k], scores f32[B,k]), async."""
-        t0 = time.perf_counter()
-        gids, scores = self._query_step(
-            self.state, jnp.asarray(queries), self._op_key()
-        )
-        self._pending += [gids, scores]
-        self.timers.query_s += time.perf_counter() - t0
+        with span("sharded.query", self.timers, "query_s"):
+            gids, scores = self._query_step(
+                self.state, jnp.asarray(queries), self._op_key()
+            )
+            self._pending += [gids, scores]
         self.timers.n_queries += int(jnp.shape(queries)[0])
         self.timers.n_ops += 1
         return gids, scores
@@ -443,15 +443,15 @@ class ShardedSession:
         if n:  # outside the insert stopwatch — gate work bills to its own
             self._ensure_room(n)  # consolidate_s / grow_s phases
         faults.crash_point("sharded-pre-dispatch")
-        t0 = time.perf_counter()
-        blocks, rows, valid, pos = shard_blocks(vecs, route, self._n_shards)
-        self.state, gids = self._insert_step(
-            self.state, blocks, rows, valid, self._op_key())
-        gids = gids.reshape(-1)[pos]
-        self._free_floor = max(self._free_floor - n, 0)
-        self._pending.append(gids)
-        self._insert_results.append(gids)
-        self.timers.insert_s += time.perf_counter() - t0
+        with span("sharded.insert", self.timers, "insert_s"):
+            blocks, rows, valid, pos = shard_blocks(vecs, route,
+                                                    self._n_shards)
+            self.state, gids = self._insert_step(
+                self.state, blocks, rows, valid, self._op_key())
+            gids = gids.reshape(-1)[pos]
+            self._free_floor = max(self._free_floor - n, 0)
+            self._pending.append(gids)
+            self._insert_results.append(gids)
         self.timers.n_inserts += n
         self.timers.n_ops += 1
         faults.crash_point("sharded-post-dispatch")
@@ -460,11 +460,10 @@ class ShardedSession:
     def delete(self, gids) -> None:
         """Owner-masked distributed delete of global ids (async)."""
         faults.crash_point("sharded-pre-dispatch")
-        t0 = time.perf_counter()
-        self.state = self._delete_step(
-            self.state, jnp.asarray(gids, jnp.int32), self._op_key()
-        )
-        self.timers.delete_s += time.perf_counter() - t0
+        with span("sharded.delete", self.timers, "delete_s"):
+            self.state = self._delete_step(
+                self.state, jnp.asarray(gids, jnp.int32), self._op_key()
+            )
         self.timers.n_deletes += int(jnp.shape(gids)[0])
         self.timers.n_ops += 1
         if self._strategy == "mask":
@@ -538,19 +537,20 @@ class ShardedSession:
         if new_capacity == self.dp.index.capacity:
             return
         faults.crash_point("sharded-pre-grow")
-        t0 = time.perf_counter()
-        if self._window_t0 is None:
-            self._window_t0 = t0
-        delta = new_capacity - self.dp.index.capacity
-        self.state = grow_state(self.state, new_capacity, axis=1)
-        self.dp = dataclasses.replace(
-            self.dp,
-            index=dataclasses.replace(self.dp.index, capacity=new_capacity),
-        )
-        self._build_steps()
-        self._free_floor += delta
-        self.timers.n_grows += 1
-        self.timers.grow_s += time.perf_counter() - t0
+        with span("sharded.grow", self.timers, "grow_s",
+                  capacity=int(new_capacity)):
+            if self._window_t0 is None:
+                self._window_t0 = time.perf_counter()
+            delta = new_capacity - self.dp.index.capacity
+            self.state = grow_state(self.state, new_capacity, axis=1)
+            self.dp = dataclasses.replace(
+                self.dp,
+                index=dataclasses.replace(self.dp.index,
+                                          capacity=new_capacity),
+            )
+            self._build_steps()
+            self._free_floor += delta
+            self.timers.n_grows += 1
         faults.crash_point("sharded-post-grow")
 
     # -- consolidation (DESIGN.md §8, per-shard) ---------------------------
@@ -569,29 +569,28 @@ class ShardedSession:
         read — the auto-trigger hands over the counts it just measured via
         ``_per_shard`` instead of reducing twice; the passes themselves
         dispatch async)."""
-        t0 = time.perf_counter()
-        per_shard = (self._per_shard_masked() if _per_shard is None
-                     else _per_shard)
-        total = int(per_shard.sum())
-        if total == 0:
-            self._masked_hint = 0
-            self.timers.consolidate_s += time.perf_counter() - t0
-            return 0
-        if self._window_t0 is None:
-            self._window_t0 = time.perf_counter()
-        mp = self.dp.index.maintenance
-        chunk = mp.consolidate_chunk or mp.delete_chunk
-        base = jax.random.fold_in(self._base_key,
-                                  ops_mod.CONSOLIDATE_KEY_STREAM)
-        for _ in range(-(-int(per_shard.max()) // chunk)):
-            # lockstep SPMD passes: a kill between passes leaves some shards
-            # drained further than others — exactly the torn-maintenance
-            # state the recovery matrix must prove replayable
-            faults.crash_point("sharded-consolidate-pass")
-            key = jax.random.fold_in(base, self._consolidate_counter)
-            self._consolidate_counter += 1
-            self.state = self._consolidate_step(self.state, key)
-        self.timers.consolidate_s += time.perf_counter() - t0
+        with span("sharded.consolidate", self.timers, "consolidate_s"):
+            per_shard = (self._per_shard_masked() if _per_shard is None
+                         else _per_shard)
+            total = int(per_shard.sum())
+            if total == 0:
+                self._masked_hint = 0
+                return 0
+            if self._window_t0 is None:
+                self._window_t0 = time.perf_counter()
+            mp = self.dp.index.maintenance
+            chunk = mp.consolidate_chunk or mp.delete_chunk
+            base = jax.random.fold_in(self._base_key,
+                                      ops_mod.CONSOLIDATE_KEY_STREAM)
+            for _ in range(-(-int(per_shard.max()) // chunk)):
+                # lockstep SPMD passes: a kill between passes leaves some
+                # shards drained further than others — exactly the
+                # torn-maintenance state the recovery matrix must prove
+                # replayable
+                faults.crash_point("sharded-consolidate-pass")
+                key = jax.random.fold_in(base, self._consolidate_counter)
+                self._consolidate_counter += 1
+                self.state = self._consolidate_step(self.state, key)
         self.timers.n_consolidations += 1
         self.timers.n_consolidated += total
         self.timers.n_ops += 1
@@ -624,17 +623,17 @@ class ShardedSession:
         arrays handed out since the last flush); settle the timers. Also a
         consolidation trigger point (DESIGN.md §8)."""
         self._maybe_consolidate()
-        t0 = time.perf_counter()
-        jax.block_until_ready(self._pending)
-        jax.block_until_ready(self.state.adj)
-        # refusal accounting (DESIGN.md §9): a full shard answers NULL gids;
-        # they are counted here (the arrays are already materialized) so a
-        # net-growing stream can never lose inserts silently
-        for gids in self._insert_results:
-            self.timers.n_refused += int((np.asarray(gids) == NULL).sum())
-        self._insert_results.clear()
-        self._pending.clear()
-        self.timers.flush_s += time.perf_counter() - t0
+        with span("sharded.flush", self.timers, "flush_s"):
+            jax.block_until_ready(self._pending)
+            jax.block_until_ready(self.state.adj)
+            # refusal accounting (DESIGN.md §9): a full shard answers NULL
+            # gids; they are counted here (the arrays are already
+            # materialized) so a net-growing stream can never lose inserts
+            # silently
+            for gids in self._insert_results:
+                self.timers.n_refused += int((np.asarray(gids) == NULL).sum())
+            self._insert_results.clear()
+            self._pending.clear()
         if self._window_t0 is not None:
             self.timers.wall_s += time.perf_counter() - self._window_t0
             self._window_t0 = None

@@ -29,6 +29,7 @@ import numpy as np
 from repro.core.graph import NULL
 from repro.core.maintenance import IPGMIndex
 from repro.core.session import Session
+from repro.core.trace import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -66,6 +67,14 @@ class BatchedServer:
 
     ``clock``/``sleep`` are injectable for deterministic tests of the
     batching window (tests/test_serving.py).
+
+    ``stats`` counts served ``batches`` and ``requests``, sheds, and
+    seconds on ``clock``: ``queue_s`` (each served request, submit →
+    leaving the queue) and ``hold_s`` (the drain holding a partial batch
+    open for arrivals). Each step writes the profiler spans
+    ``ipgm.serve.step`` ⊃ ``ipgm.serve.drain`` ⊃ ``ipgm.serve.hold``, then
+    ``ipgm.serve.pad``, the session's spans (the device wait and the copy
+    to the host among them), and ``ipgm.serve.scatter``.
     """
 
     _POLL_S = 0.0005  # wait-slice; bounds drain latency jitter, not a spin
@@ -89,8 +98,8 @@ class BatchedServer:
         self._queue: deque[tuple[int, np.ndarray, float]] = deque()
         self._next_id = 0
         self._ready = True
-        self.stats = {"batches": 0, "requests": 0, "pad_waste": 0.0,
-                      "shed_overload": 0, "shed_deadline": 0}
+        self.stats = {"batches": 0, "requests": 0, "shed_overload": 0,
+                      "shed_deadline": 0, "queue_s": 0.0, "hold_s": 0.0}
         # rid → reason for every request shed after admission (deadline):
         # callers poll this the same way they poll step() results
         self.failed: dict[int, str] = {}
@@ -118,7 +127,7 @@ class BatchedServer:
         self._queue.append((rid, np.asarray(query, np.float32), self._clock()))
         return rid
 
-    def _expire(self) -> None:
+    def _expire(self, now: float) -> None:
         """Fail queued requests whose age exceeds ``deadline_s`` — they shed
         *before* padding/dispatch, so a stale backlog never spends a device
         step producing answers nobody is waiting for. Submit times are
@@ -126,7 +135,6 @@ class BatchedServer:
         amortized per drained request)."""
         if self.cfg.deadline_s is None:
             return
-        now = self._clock()
         while self._queue and now - self._queue[0][2] > self.cfg.deadline_s:
             rid, _, _ = self._queue.popleft()
             self.failed[rid] = "deadline"
@@ -145,39 +153,54 @@ class BatchedServer:
         """
         out: list[tuple[int, np.ndarray]] = []
         deadline = self._clock() + self.cfg.max_wait_s
-        while len(out) < self.cfg.max_batch:
-            self._expire()
-            if self._queue:
-                rid, q, _ = self._queue.popleft()
-                out.append((rid, q))
-                continue
-            if not out:
-                break  # idle server: nothing to wait *for*
-            remaining = deadline - self._clock()
-            if remaining <= 0:
-                break
-            self._sleep(min(remaining, self._POLL_S))
+        with span("serve.drain"):
+            self._take(out)
+            if not out or len(out) == self.cfg.max_batch:
+                return out  # idle, or full: nothing to hold open for
+            with span("serve.hold", self.stats, "hold_s", clock=self._clock):
+                while len(out) < self.cfg.max_batch:
+                    remaining = deadline - self._clock()
+                    if remaining <= 0:
+                        break
+                    self._sleep(min(remaining, self._POLL_S))
+                    self._take(out)
         return out
+
+    def _take(self, out: list[tuple[int, np.ndarray]]) -> None:
+        """Move queued requests into ``out`` until it holds ``max_batch``
+        or the queue is empty, shedding expired ones first."""
+        now = self._clock()
+        self._expire(now)
+        while len(out) < self.cfg.max_batch and self._queue:
+            rid, q, t_submit = self._queue.popleft()
+            self.stats["queue_s"] += now - t_submit
+            out.append((rid, q))
 
     def step(self) -> dict[int, tuple[np.ndarray, np.ndarray]]:
         """Serve one batch; returns {request_id: (ids, scores)}."""
-        batch = self._drain()
-        if not batch:
-            return {}
-        B = self.cfg.max_batch
-        dim = batch[0][1].shape[-1]
-        padded = np.zeros((B, dim), np.float32)
-        for i, (_, q) in enumerate(batch):
-            padded[i] = q
-        # one op-IR micro-batch at the pad-stable max_batch shape; the
-        # handle resolves (blocks) only when this step's results are needed
-        ids, scores = self.session.query(
-            padded, k=self.cfg.k, chunk=B
-        ).result()
-        self.stats["batches"] += 1
-        self.stats["requests"] += len(batch)
-        self.stats["pad_waste"] += 1.0 - len(batch) / B
-        return {rid: (ids[i], scores[i]) for i, (rid, _) in enumerate(batch)}
+        if not self._queue:
+            return {}  # idle: no step, and no span
+        with span("serve.step", step=self.stats["batches"]) as ann:
+            batch = self._drain()
+            if not batch:
+                return {}
+            ann.set_metadata(rid0=batch[0][0], n=len(batch))
+            with span("serve.pad"):
+                B = self.cfg.max_batch
+                dim = batch[0][1].shape[-1]
+                padded = np.zeros((B, dim), np.float32)
+                for i, (_, q) in enumerate(batch):
+                    padded[i] = q
+            # one op-IR micro-batch at the pad-stable max_batch shape; the
+            # handle resolves (blocks) only when this step's results are
+            # needed
+            ids, scores = self.session.query(
+                padded, k=self.cfg.k, chunk=B).result()
+            self.stats["batches"] += 1
+            self.stats["requests"] += len(batch)
+            with span("serve.scatter"):
+                return {rid: (ids[i], scores[i])
+                        for i, (rid, _) in enumerate(batch)}
 
 
 def quorum_merge(

@@ -97,6 +97,30 @@ def test_drain_idle_and_full_batch_skip_the_wait():
     assert len(srv._queue) == 1
 
 
+
+def test_stats_count_hold_and_queue_wait_on_the_injected_clock():
+    """``hold_s`` is the simulated hold of a partial batch; ``queue_s`` sums
+    each served request's submit → leaving the queue."""
+    ft = _FakeTime()
+    srv = _server(ServeConfig(max_batch=4, max_wait_s=0.005, k=3), ft)
+    srv.submit(np.zeros(8, np.float32))           # t = 0
+    ft.now = 0.001
+    srv.submit(np.ones(8, np.float32))            # t = 0.001
+    ft.now = 0.003                                # the step starts
+
+    def late_arrival(now):
+        if now >= 0.004 and srv._next_id == 2:
+            srv.submit(np.full(8, 2.0, np.float32))   # taken at once
+
+    ft.on_sleep = late_arrival
+    assert len(srv.step()) == 3
+    # three in hand, room for four: held to the deadline, 0.003 + 0.005
+    assert srv.stats["hold_s"] == pytest.approx(0.005)
+    assert srv.stats["hold_s"] == pytest.approx(sum(ft.sleeps))
+    assert srv.stats["queue_s"] == pytest.approx(0.003 + 0.002 + 0.0)
+    assert srv.stats["batches"] == 1 and srv.stats["requests"] == 3
+
+
 def test_quorum_merge_degrades_gracefully():
     rng = np.random.default_rng(1)
     P, B, k = 8, 4, 10
